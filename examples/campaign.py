@@ -20,6 +20,7 @@ import argparse
 
 from repro.core import campaign, chromosome
 from repro.data import uci_synth
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -151,6 +152,7 @@ def main():
     except ValueError as e:
         ap.error(str(e))
 
+    enable_compile_cache()
     res = campaign.run_campaign(cfg)
     print(res.table)
     deferred = f", {res.n_deferred} surrogate-deferred" if args.surrogate else ""

@@ -217,27 +217,18 @@ def make_population_evaluator(
             arr, shd.logical_sharding(arr.shape, axes, pop_mesh, rules)
         )
 
-    def _deliberately_placed(a):
-        # multi-device sharding is a caller decision we must honor; a
-        # default-placed (single-device) array on a multi-device host is
-        # NOT — it falls through to the auto-shard path below
-        return isinstance(a, jax.Array) and (
-            n_dev == 1 or len(a.sharding.device_set) > 1
-        )
-
     def evaluate(*args):
         P = np.shape(args[0])[0]
-        if P % granule == 0 and all(_deliberately_placed(a) for a in args):
-            # caller already sharded its device arrays (its own mesh):
-            # honor that placement, no host round-trip or re-shard
-            return _evaluate_padded(*args)
-        args = [np.asarray(a) for a in args]
         bucket = -(-P // granule) * granule
-        if bucket != P:
-            # edge-replicate: padded rows are valid chromosomes, just unused
-            args = [np.concatenate([a, np.repeat(a[-1:], bucket - P, 0)]) for a in args]
-        acc = _evaluate_padded(*(_shard(a) for a in args))
-        return acc[:P]
+        if bucket == P:
+            # device arrays, even ones a caller placed on its own mesh with
+            # Explicit axes, move device-to-device onto this evaluator's
+            # Auto mesh: no host round-trip, and one program for any caller
+            return _evaluate_padded(*(_shard(a) for a in args))
+        # edge-replicate: padded rows are valid chromosomes, just unused
+        args = [np.asarray(a) for a in args]
+        args = [np.concatenate([a, np.repeat(a[-1:], bucket - P, 0)]) for a in args]
+        return _evaluate_padded(*(_shard(a) for a in args))[:P]
 
     def dispatch(*args):
         """Launch the batch's program now; block in the returned resolve.
@@ -265,6 +256,8 @@ def make_population_evaluator(
 
     evaluate.dispatch = dispatch
     evaluate.mesh = pop_mesh
+    evaluate.shard_fn = _shard        # introspection hooks: the placement
+    evaluate.program = _evaluate_padded  # and the jitted program to lower
     evaluate.rebuild = rebuild
     return evaluate
 
@@ -402,6 +395,7 @@ def make_island_evaluator(
     evaluate.mesh = isl_mesh          # introspection hooks for tests and
     evaluate.granule = granule        # benchmarks: the device-group layout
     evaluate.shard_fn = _shard        # the stacked tensors are placed with
+    evaluate.program = _evaluate_stacked
     evaluate.dispatch = dispatch
     evaluate.rebuild = rebuild
     return evaluate

@@ -19,9 +19,10 @@ axis into an extra sequential grid dimension and ``custom_vjp`` batches the
 fwd/bwd pair, which is exactly how ``core.trainer``'s population-vmapped
 evaluator consumes this op with heterogeneous per-genome threshold tables.
 
-``interpret=None`` auto-detects the backend: compiled on TPU, Pallas
-interpreter elsewhere (the CPU CI fallback — same kernel code, executed
-serially with jnp semantics).
+``interpret=None`` picks the mode from the backend: compiled on TPU, the
+Pallas interpreter on CPU (the test backend — same kernel code, executed
+serially with jnp semantics), and an error on any other backend rather
+than a silent interpreted run.
 """
 
 from __future__ import annotations
@@ -42,7 +43,13 @@ __all__ = ["fused_qat_first_layer"]
 
 
 def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise NotImplementedError(
+            f"fused_qat compiles for TPU and interprets on CPU; backend "
+            f"{backend!r} is neither"
+        )
+    return backend == "cpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))

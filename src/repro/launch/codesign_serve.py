@@ -29,6 +29,7 @@ import time
 import numpy as np
 
 from repro.core import codesign, eval_service, nsga2
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import admission as admission_rt
 
 
@@ -80,6 +81,10 @@ def serve_workload(
 
 
 def main(argv: list[str] | None = None) -> dict:
+    """Serve the workload and print its report.
+
+    Raises ``SystemExit`` with a non-zero code when any request failed.
+    """
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--dataset", default="seeds")
     ap.add_argument("--adc-bits", type=int, default=4)
@@ -102,6 +107,7 @@ def main(argv: list[str] | None = None) -> dict:
                     help="memo-trained surrogate pre-screening per request "
                          "(core.surrogate; fresh screen per search)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cd_cfg = codesign.CodesignConfig(
         dataset=args.dataset, adc_bits=args.adc_bits, seed=args.seed,
@@ -155,6 +161,12 @@ def main(argv: list[str] | None = None) -> dict:
           f"{sm['hits']} hits + {sm['coalesced']} coalesced "
           f"(cross-request hit rate {stats['hit_rate']:.1%})")
     print(f"admission: {stats['admission']}")
+    failed = [r.request_id for r in results if not r.ok]
+    if failed:
+        raise SystemExit(
+            f"{len(failed)} of {len(results)} requests failed: "
+            + ", ".join(failed)
+        )
     return {"results": results, "stats": stats}
 
 

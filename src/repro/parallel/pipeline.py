@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = ["pipeline_apply"]
 
@@ -79,12 +78,12 @@ def pipeline_apply(
         outputs = jnp.where(is_last, outputs, jnp.zeros_like(outputs))
         return jax.lax.psum(outputs, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(stage_params, xs)
     return out.reshape((batch,) + out.shape[2:])

@@ -37,7 +37,7 @@ import warnings
 
 import numpy as np
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # logical axis -> mesh axes (tuple = composed axes, None = replicated)
 LOGICAL_RULES: dict[str, tuple[str, ...] | None] = {
@@ -61,6 +61,24 @@ LOGICAL_RULES: dict[str, tuple[str, ...] | None] = {
     "stage": ("stage",),          # pipeline parallelism (opt-in meshes)
     "seq_tp": ("model",),         # context-parallel fallback (heads % TP != 0)
 }
+
+
+def make_mesh(
+    shape: tuple[int, ...], axis_names: tuple[str, ...], devices=None
+) -> Mesh:
+    """``jax.make_mesh`` with the one axis-type choice of this repo: Auto.
+
+    Every mesh in ``src/`` is built here.  JAX's default is Explicit axes,
+    under which a sharding is part of each array's type and an op whose
+    output sharding JAX cannot infer raises instead of letting XLA pick
+    one.  The QAT row program gathers a minibatch ``X_tr[idx]`` with
+    population-sharded indices from a replicated table, which is such an
+    op.  Auto axes leave that choice to XLA's sharding propagation.
+    """
+    return jax.make_mesh(
+        shape, axis_names, axis_types=(AxisType.Auto,) * len(axis_names),
+        devices=devices,
+    )
 
 
 def population_rules() -> dict[str, tuple[str, ...] | None]:
@@ -101,7 +119,7 @@ def population_mesh(
         devices = jax.devices()
         if n_devices is not None:
             devices = devices[:n_devices]
-    return jax.make_mesh((len(devices),), ("data",), devices=devices)
+    return make_mesh((len(devices),), ("data",), devices=devices)
 
 
 def island_rules() -> dict[str, tuple[str, ...] | None]:
@@ -145,7 +163,7 @@ def island_mesh(
         raise ValueError(f"num_islands must be >= 1, got {num_islands}")
     group = n // num_islands
     if group < 1:
-        return jax.make_mesh((1, n), ("island", "data"), devices=devices)
+        return make_mesh((1, n), ("island", "data"), devices=devices)
     used = group * num_islands
     if used != n:
         dropped = ", ".join(str(d) for d in devices[used:])
@@ -155,7 +173,7 @@ def island_mesh(
             f"mesh and dropping [{dropped}]",
             stacklevel=2,
         )
-    return jax.make_mesh(
+    return make_mesh(
         (num_islands, group), ("island", "data"), devices=devices[:used]
     )
 

@@ -539,3 +539,28 @@ def test_concurrent_qat_search_equals_solo_real_evaluator():
     _assert_result_matches_solo(results[0], solo_engine, solo_out)
     assert results[1].ok
     assert stats["shared_memo"]["trained"] >= 1
+
+
+@pytest.mark.ci
+def test_codesign_serve_exits_nonzero_when_a_request_fails(monkeypatch):
+    """The serve CLI reports failed requests and exits non-zero."""
+    from repro.core import codesign
+    from repro.launch import codesign_serve
+
+    def broken(batches):
+        raise failure_rt.DeviceLossError("wave lost")
+
+    def backend(cfg, wave_slots=4):
+        return {
+            "stacked_evaluate": broken, "fingerprint": {},
+            "n_mask_bits": N_BITS, "cat_cardinalities": CATS,
+            "screen_factory": None,
+        }
+
+    monkeypatch.setattr(codesign, "make_service_backend", backend)
+    monkeypatch.setattr(codesign_serve, "enable_compile_cache", lambda: None)
+    with pytest.raises(SystemExit) as exc:
+        codesign_serve.main(
+            ["--requests", "2", "--pop", "4", "--gens", "1", "--slots", "2"]
+        )
+    assert "2 of 2 requests failed" in str(exc.value.code)

@@ -23,6 +23,7 @@ import pytest
 from repro.core import codesign, qat, trainer
 from repro.data import uci_synth
 from repro.kernels.fused_qat import fused_qat_first_layer
+from repro.kernels.fused_qat import ops as fq_ops
 from repro.kernels.fused_qat import ref as fq_ref
 
 
@@ -182,3 +183,18 @@ def test_codesign_fused_identical_pareto_front():
     np.testing.assert_array_equal(r_fused.front_cats, r_ref.front_cats)
     np.testing.assert_array_equal(r_fused.front_acc, r_ref.front_acc)
     assert r_fused.conv_acc == r_ref.conv_acc
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False)])
+def test_auto_interpret_compiles_on_tpu_interprets_on_cpu(
+    backend, interpret, monkeypatch
+):
+    monkeypatch.setattr(fq_ops.jax, "default_backend", lambda: backend)
+    assert fq_ops._auto_interpret() is interpret
+
+
+def test_auto_interpret_refuses_other_backends(monkeypatch):
+    """No silent interpreted run on an accelerator the kernel was not built for."""
+    monkeypatch.setattr(fq_ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError, match="gpu"):
+        fq_ops._auto_interpret()

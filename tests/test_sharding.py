@@ -94,3 +94,54 @@ def test_island_mesh_single_device_fallback():
 def test_island_mesh_rejects_bad_island_count():
     with pytest.raises(ValueError):
         shd.island_mesh(0)
+
+
+def test_mesh_builders_use_auto_axes():
+    """Every mesh in src/ comes from shd.make_mesh, with Auto axis types."""
+    from jax.sharding import AxisType
+
+    from repro.launch import mesh as launch_mesh
+
+    meshes = [
+        shd.population_mesh(),
+        shd.island_mesh(4),
+        shd.island_mesh(1),
+        launch_mesh.make_mesh((1, 1)),
+        launch_mesh.make_mesh((1, 1, 1)),
+    ]
+    for m in meshes:
+        assert m.axis_types == (AxisType.Auto,) * len(m.axis_names), m
+
+
+def test_population_evaluator_same_rows_on_caller_explicit_mesh():
+    """Rows a caller placed on its own default (Explicit-axes) mesh score
+    exactly as host rows do on the evaluator's own population mesh."""
+    import numpy as np
+    from jax.sharding import AxisType, NamedSharding
+
+    from repro.core import qat, trainer
+    from repro.data import uci_synth
+
+    X, y, spec = uci_synth.load("seeds")
+    Xtr, ytr, Xte, yte = uci_synth.stratified_split(X, y)
+    cfg = qat.MLPConfig((spec.n_features, spec.hidden, spec.n_classes))
+    ev = trainer.make_population_evaluator(
+        Xtr, ytr, Xte, yte, cfg, trainer.EvalConfig(max_steps=20, step_scale=0.2)
+    )
+    n = 4
+    rng = np.random.default_rng(0)
+    rows = [
+        rng.uniform(size=(n, spec.n_features, 16)) < 0.7,
+        np.full(n, 8.0, np.float32), np.full(n, 4.0, np.float32),
+        np.asarray([16, 32, 64, 128], np.int32), np.full(n, 40, np.int32),
+        np.full(n, 0.05, np.float32), np.arange(n, dtype=np.int32),
+    ]
+    acc_host = np.asarray(ev(*rows))
+
+    caller_mesh = jax.make_mesh((1,), ("data",))
+    assert caller_mesh.axis_types == (AxisType.Explicit,)
+    placed = [
+        jax.device_put(r, NamedSharding(caller_mesh, P("data", *[None] * (r.ndim - 1))))
+        for r in rows
+    ]
+    np.testing.assert_array_equal(np.asarray(ev(*placed)), acc_host)
