@@ -24,7 +24,7 @@ import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import area as area_model
-from repro.core import chromosome, hybrid, memo_store, nsga2, qat, surrogate, trainer
+from repro.core import chromosome, hybrid, memo_store, nsga2, qat, spans, surrogate, trainer
 from repro.data import uci_synth
 from repro.runtime import elastic as elastic_rt
 from repro.runtime import failure as failure_rt
@@ -398,35 +398,41 @@ def _make_cost_batch(axes: tuple[str, ...], adc_bits: int, layer_sizes):
 
 
 def run_codesign(cfg: CodesignConfig) -> CodesignResult:
+    with spans.span("codesign.search", seed=cfg.seed, dataset=cfg.dataset):
+        return _run_codesign(cfg)
+
+
+def _run_codesign(cfg: CodesignConfig) -> CodesignResult:
     cfg.validate()
-    X, y, spec = uci_synth.load(cfg.dataset)
-    X_tr, y_tr, X_te, y_te = uci_synth.stratified_split(X, y, 0.7, cfg.seed)
-    mlp_cfg = qat.MLPConfig(
-        layer_sizes=(spec.n_features, spec.hidden, spec.n_classes),
-        adc_bits=cfg.adc_bits,
-    )
-    axes = cfg.axes()
-    n_layers = len(mlp_cfg.layer_sizes) - 1
-    eval_cfg = trainer.EvalConfig(
-        max_steps=cfg.max_steps, step_scale=cfg.step_scale, seed=cfg.seed,
-        use_fused_kernel=cfg.use_fused_kernel, genome_axes=axes,
-    )
-    # evaluators live in a mutable dict so the elastic-recovery path can
-    # swap in re-meshed replacements mid-campaign: every objective callback
-    # below reads the dict at call time, not at closure-capture time
-    evaluators: dict = {
-        "pop": trainer.make_population_evaluator(
-            X_tr, y_tr, X_te, y_te, mlp_cfg, eval_cfg,
+    with spans.span("codesign.setup"):
+        X, y, spec = uci_synth.load(cfg.dataset)
+        X_tr, y_tr, X_te, y_te = uci_synth.stratified_split(X, y, 0.7, cfg.seed)
+        mlp_cfg = qat.MLPConfig(
+            layer_sizes=(spec.n_features, spec.hidden, spec.n_classes),
+            adc_bits=cfg.adc_bits,
         )
-    }
+        axes = cfg.axes()
+        n_layers = len(mlp_cfg.layer_sizes) - 1
+        eval_cfg = trainer.EvalConfig(
+            max_steps=cfg.max_steps, step_scale=cfg.step_scale, seed=cfg.seed,
+            use_fused_kernel=cfg.use_fused_kernel, genome_axes=axes,
+        )
+        # evaluators live in a mutable dict so the elastic-recovery path can
+        # swap in re-meshed replacements mid-campaign: every objective callback
+        # below reads the dict at call time, not at closure-capture time
+        evaluators: dict = {
+            "pop": trainer.make_population_evaluator(
+                X_tr, y_tr, X_te, y_te, mlp_cfg, eval_cfg,
+            )
+        }
 
-    def rebuild_evaluators(n_devices: int | None = None) -> None:
-        """Re-lower every evaluator onto the first ``n_devices`` devices."""
-        for name in list(evaluators):
-            evaluators[name] = evaluators[name].rebuild(n_devices)
+        def rebuild_evaluators(n_devices: int | None = None) -> None:
+            """Re-lower every evaluator onto the first ``n_devices`` devices."""
+            for name in list(evaluators):
+                evaluators[name] = evaluators[name].rebuild(n_devices)
 
-    conv_area, conv_power = area_model.conventional_cost(spec.n_features, cfg.adc_bits)
-    cost_batch, norm_area, _ = _make_cost_batch(axes, cfg.adc_bits, mlp_cfg.layer_sizes)
+        conv_area, conv_power = area_model.conventional_cost(spec.n_features, cfg.adc_bits)
+        cost_batch, norm_area, _ = _make_cost_batch(axes, cfg.adc_bits, mlp_cfg.layer_sizes)
 
     # chaos-drill tap: every batch actually sent to an evaluator passes
     # through here (one ordinal per non-empty batch, row count accumulated)
@@ -456,11 +462,12 @@ def run_codesign(cfg: CodesignConfig) -> CodesignResult:
         WHILE the devices train, and the returned closure blocks and
         assembles the (1 − acc, area ratio) objectives at commit time.
         """
-        dec = chromosome.decode_batch(
-            mask_genes, cat_genes, spec.n_features, cfg.adc_bits,
-            axes=axes, n_layers=n_layers,
-        )
-        seeds = _genome_seeds(mask_genes, cat_genes)
+        with spans.span("codesign.decode"):
+            dec = chromosome.decode_batch(
+                mask_genes, cat_genes, spec.n_features, cfg.adc_bits,
+                axes=axes, n_layers=n_layers,
+            )
+            seeds = _genome_seeds(mask_genes, cat_genes)
         _observe_batch(mask_genes.shape[0])
         resolve_acc = evaluators["pop"].dispatch(
             dec["masks"], dec["weight_bits"], dec["act_bits"],
@@ -468,10 +475,12 @@ def run_codesign(cfg: CodesignConfig) -> CodesignResult:
             *_extra_rows(dec),
         )
         # host-side objective tail, overlapped with the in-flight program
-        areas, _ = cost_batch(dec)
+        with spans.span("codesign.area"):
+            areas, _ = cost_batch(dec)
 
         def resolve() -> np.ndarray:
-            accs = np.asarray(resolve_acc())
+            with spans.span("codesign.wait"):
+                accs = np.asarray(resolve_acc())
             return np.stack([1.0 - accs, areas / norm_area], axis=1)
 
         return resolve
@@ -623,13 +632,6 @@ def run_codesign(cfg: CodesignConfig) -> CodesignResult:
     if cfg.memo_path and cfg.memoize:
         memo_store.save_memo(cfg.memo_path, ga.memo, cfg.memo_fingerprint())
 
-    dec = chromosome.decode_batch(
-        out["masks"], out["cats"], spec.n_features, cfg.adc_bits,
-        axes=axes, n_layers=n_layers,
-    )
-    front_area, front_power = cost_batch(dec)
-    front_acc = 1.0 - out["objs"][:, 0]
-
     # conventional-ADC baseline accuracy = full mask + default hyper-params,
     # evaluated explicitly over several inits (the [7] baseline is a tuned
     # bespoke circuit — take the best-trained replicate, not a lucky/unlucky
@@ -637,47 +639,55 @@ def run_codesign(cfg: CodesignConfig) -> CodesignResult:
     # seeds: the GA-facing ``evaluate`` derives seeds from the genome, which
     # would collapse identical replicates onto one init.
     n_seeds = 4
-    # all-zero categorical genes decode to the default/exact choice of
-    # every gene group (po2-8 weights, exact ReLU), so the baseline stays
-    # the [7] bespoke circuit whatever axes the search evolves
-    base_cats = np.zeros(
-        (n_seeds, len(chromosome.cat_cardinalities(axes, n_layers))), np.int64
-    )
-    base = chromosome.decode_batch(
-        np.ones((n_seeds, chromosome.n_mask_bits(spec.n_features, cfg.adc_bits)), bool),
-        base_cats, spec.n_features, cfg.adc_bits,
-        axes=axes, n_layers=n_layers,
-    )
-    base_accs = np.asarray(
-        evaluators["pop"](
-            base["masks"], base["weight_bits"], base["act_bits"],
-            base["batch_size"], base["epochs"], base["lr"],
-            np.arange(n_seeds, dtype=np.int32),
-            *_extra_rows(base),
+    with spans.span("codesign.baseline"):
+        # all-zero categorical genes decode to the default/exact choice of
+        # every gene group (po2-8 weights, exact ReLU), so the baseline stays
+        # the [7] bespoke circuit whatever axes the search evolves
+        base_cats = np.zeros(
+            (n_seeds, len(chromosome.cat_cardinalities(axes, n_layers))), np.int64
         )
-    )
-    conv_acc = float(base_accs.max())
+        base = chromosome.decode_batch(
+            np.ones((n_seeds, chromosome.n_mask_bits(spec.n_features, cfg.adc_bits)), bool),
+            base_cats, spec.n_features, cfg.adc_bits,
+            axes=axes, n_layers=n_layers,
+        )
+        base_accs = np.asarray(
+            evaluators["pop"](
+                base["masks"], base["weight_bits"], base["act_bits"],
+                base["batch_size"], base["epochs"], base["lr"],
+                np.arange(n_seeds, dtype=np.int32),
+                *_extra_rows(base),
+            )
+        )
+        conv_acc = float(base_accs.max())
 
-    return CodesignResult(
-        dataset=cfg.dataset,
-        spec=spec,
-        front_masks=dec["masks"],
-        front_cats=out["cats"],
-        front_acc=front_acc,
-        front_area=front_area,
-        front_power=front_power,
-        conv_acc=conv_acc,
-        conv_area=conv_area,
-        conv_power=conv_power,
-        history=out["history"],
-        n_evaluations=int(out["n_evaluations"]),
-        n_memo_hits=int(out["n_memo_hits"]),
-        n_deferred=int(out.get("n_deferred", 0)),
-        island_history=out.get("island_history"),
-        migrations=out.get("migrations"),
-        recoveries=recoveries,
-        genome_axes=axes,
-    )
+    with spans.span("codesign.result"):
+        dec = chromosome.decode_batch(
+            out["masks"], out["cats"], spec.n_features, cfg.adc_bits,
+            axes=axes, n_layers=n_layers,
+        )
+        front_area, front_power = cost_batch(dec)
+        front_acc = 1.0 - out["objs"][:, 0]
+        return CodesignResult(
+            dataset=cfg.dataset,
+            spec=spec,
+            front_masks=dec["masks"],
+            front_cats=out["cats"],
+            front_acc=front_acc,
+            front_area=front_area,
+            front_power=front_power,
+            conv_acc=conv_acc,
+            conv_area=conv_area,
+            conv_power=conv_power,
+            history=out["history"],
+            n_evaluations=int(out["n_evaluations"]),
+            n_memo_hits=int(out["n_memo_hits"]),
+            n_deferred=int(out.get("n_deferred", 0)),
+            island_history=out.get("island_history"),
+            migrations=out.get("migrations"),
+            recoveries=recoveries,
+            genome_axes=axes,
+        )
 
 
 def make_service_backend(cfg: CodesignConfig, wave_slots: int = 4) -> dict:

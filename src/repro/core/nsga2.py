@@ -20,7 +20,9 @@ what ``benchmarks/ga_runtime.py`` uses as the re-evaluation baseline.
 
 ``history`` records per-generation telemetry: front size, best objectives,
 rows actually evaluated (``n_evals``), memo hits, evaluation wall-clock
-(``eval_s``) and total generation wall-clock (``gen_s``).
+(``eval_s``) and total generation wall-clock (``gen_s``), the durations of
+the generation's ``nsga2.evaluate`` and ``nsga2.generation`` spans
+(``core.spans``).
 
 Begin/commit phase contract: ``setup`` and ``step`` are each the exact
 composition of a ``*_begin`` phase and a ``*_commit`` phase with the
@@ -96,7 +98,7 @@ from typing import Callable, Sequence
 
 import numpy as np
 
-from repro.core import evalpipe
+from repro.core import evalpipe, spans
 
 __all__ = [
     "fast_non_dominated_sort",
@@ -363,7 +365,7 @@ class NSGA2:
         self.gen = 0
         # in-flight pool between a *_begin and its *_commit (lock-step mode)
         self._pending: tuple[np.ndarray, np.ndarray] | None = None
-        self._t_gen = 0.0
+        self._gen_span: spans.Span | None = None
         self._evals_before = 0
         self._hits_before = 0
         self._deferred_before = 0
@@ -493,8 +495,9 @@ class NSGA2:
         """Select generation 0 from the evaluated seed pool."""
         masks, cats = self._pending
         self._pending = None
-        objs = np.asarray(objs, np.float64)
-        idx, rank, crowd = self._select(objs, self.cfg.pop_size)
+        with spans.span("nsga2.select"):
+            objs = np.asarray(objs, np.float64)
+            idx, rank, crowd = self._select(objs, self.cfg.pop_size)
         self.pop = Genome(masks[idx], cats[idx])
         self.objs = objs[idx]
         self.rank, self.crowd = rank, crowd
@@ -502,54 +505,62 @@ class NSGA2:
 
     def setup(self) -> None:
         """Draw and evaluate generation 0, establish rank/crowding."""
-        masks, cats = self.setup_begin()
-        self.setup_commit(self._evaluate(masks, cats))
+        with spans.span("nsga2.setup"):
+            masks, cats = self.setup_begin()
+            self.setup_commit(self._evaluate(masks, cats))
 
     def step_begin(self) -> tuple[np.ndarray, np.ndarray]:
-        """Variation phase: returns the parent+child pool to evaluate."""
-        self._t_gen = time.perf_counter()
+        """Variation phase: returns the parent+child pool to evaluate.
+
+        Opens the generation's span; :meth:`step_commit` closes it."""
+        self._gen_span = spans.span("nsga2.generation", gen=self.gen).__enter__()
         self._evals_before = self.n_evaluations
         self._hits_before = self.n_memo_hits
         self._deferred_before = self.n_deferred
-        kids = self._make_children(self.pop, self.rank, self.crowd)
-        allm = np.concatenate([self.pop.masks, kids.masks])
-        allc = np.concatenate([self.pop.cats, kids.cats])
-        if (
-            self._refine is not None
-            and (self.gen + 1) % self._refine_every == 0
-        ):
-            # refinement wave: gradient-polish the top-crowding front-0
-            # members (the emigrant pick — deterministic, no host RNG) and
-            # append the results as extra children.  _select handles the
-            # larger pool; the plan/dedupe path prices a refined child
-            # equal to its parent (or to any resident) at zero rows.
-            em, ec, _ = self.emigrants(self._refine_top_k)
-            rm, rc = self._refine(em, ec)
-            allm = np.concatenate([allm, np.asarray(rm, bool)])
-            allc = np.concatenate([allc, np.asarray(rc, np.int64)])
+        with spans.span("nsga2.variation"):
+            kids = self._make_children(self.pop, self.rank, self.crowd)
+            allm = np.concatenate([self.pop.masks, kids.masks])
+            allc = np.concatenate([self.pop.cats, kids.cats])
+            if (
+                self._refine is not None
+                and (self.gen + 1) % self._refine_every == 0
+            ):
+                # refinement wave: gradient-polish the top-crowding front-0
+                # members (the emigrant pick — deterministic, no host RNG) and
+                # append the results as extra children.  _select handles the
+                # larger pool; the plan/dedupe path prices a refined child
+                # equal to its parent (or to any resident) at zero rows.
+                em, ec, _ = self.emigrants(self._refine_top_k)
+                rm, rc = self._refine(em, ec)
+                allm = np.concatenate([allm, np.asarray(rm, bool)])
+                allc = np.concatenate([allc, np.asarray(rc, np.int64)])
         self._pending = (allm, allc)
         return allm, allc
 
     def step_commit(self, allo: np.ndarray, eval_s: float) -> dict:
-        """Selection + telemetry on the evaluated pool from step_begin."""
+        """Selection + telemetry on the evaluated pool from step_begin;
+        closes the generation's span, whose duration is ``gen_s``."""
         allm, allc = self._pending
         self._pending = None
-        allo = np.asarray(allo, np.float64)
-        idx, rank, crowd = self._select(allo, self.cfg.pop_size)
-        self.pop, self.objs = Genome(allm[idx], allc[idx]), allo[idx]
-        self.rank, self.crowd = rank, crowd
-        front0 = fast_non_dominated_sort(self.objs)[0]
-        rec = {
-            "gen": self.gen,
-            "front_size": int(front0.size),
-            "best_obj0": float(self.objs[:, 0].min()),
-            "best_obj1": float(self.objs[:, 1].min()) if self.objs.shape[1] > 1 else None,
-            "n_evals": int(self.n_evaluations - self._evals_before),
-            "memo_hits": int(self.n_memo_hits - self._hits_before),
-            "deferred": int(self.n_deferred - self._deferred_before),
-            "eval_s": round(eval_s, 4),
-            "gen_s": round(time.perf_counter() - self._t_gen, 4),
-        }
+        with spans.span("nsga2.select"):
+            allo = np.asarray(allo, np.float64)
+            idx, rank, crowd = self._select(allo, self.cfg.pop_size)
+            self.pop, self.objs = Genome(allm[idx], allc[idx]), allo[idx]
+            self.rank, self.crowd = rank, crowd
+            front0 = fast_non_dominated_sort(self.objs)[0]
+            rec = {
+                "gen": self.gen,
+                "front_size": int(front0.size),
+                "best_obj0": float(self.objs[:, 0].min()),
+                "best_obj1": float(self.objs[:, 1].min()) if self.objs.shape[1] > 1 else None,
+                "n_evals": int(self.n_evaluations - self._evals_before),
+                "memo_hits": int(self.n_memo_hits - self._hits_before),
+                "deferred": int(self.n_deferred - self._deferred_before),
+                "eval_s": round(eval_s, 4),
+            }
+        gen_span, self._gen_span = self._gen_span, None
+        gen_span.__exit__(None, None, None)
+        rec["gen_s"] = round(gen_span.dur, 4)
         self.history.append(rec)
         self.gen += 1
         return rec
@@ -557,11 +568,11 @@ class NSGA2:
     def step(self) -> dict:
         """Advance one generation; returns the telemetry record."""
         allm, allc = self.step_begin()
-        t_eval = time.perf_counter()
         # the full parent+child pool goes through the memo: survivors and
         # duplicate children cost nothing, only new genomes are trained
-        allo = self._evaluate(allm, allc)
-        return self.step_commit(allo, time.perf_counter() - t_eval)
+        with spans.span("nsga2.evaluate") as ev:
+            allo = self._evaluate(allm, allc)
+        return self.step_commit(allo, ev.dur)
 
     # -- the pipeline halves (every driver schedules over these) -------------
 
@@ -606,31 +617,32 @@ class NSGA2:
         honesty contract in ``evalpipe.resolve_decision`` guarantees they
         are never answered by a surrogate prediction.
         """
-        keys = genome_keys(masks, cats)
-        with self._memo_lock:
-            unseen = evalpipe.plan_rows(self._memo, keys, claimed)
-            if self._screen is None or not unseen:
-                return evalpipe.PoolPlan(keys=keys, train=unseen)
-            must = frozenset(k for k in unseen if k in self._deferred)
-            if force_train is not None:
-                must = must | frozenset(k for k in unseen if k in force_train)
-            ctx = evalpipe.ScreenContext(
-                masks=masks,
-                cats=cats,
-                keys=keys,
-                unseen=dict(unseen),
-                memo=self._memo,
-                must_train=must,
-                final=self._screen_final(),
-            )
-            decision = evalpipe.resolve_decision(ctx, self._screen(ctx))
-            self._deferred.update(decision.deferred)
-            return evalpipe.PoolPlan(
-                keys=keys,
-                train=decision.train,
-                deferred={k: unseen[k] for k in decision.deferred},
-                screen_info=decision.telemetry,
-            )
+        with spans.span("nsga2.plan"):
+            keys = genome_keys(masks, cats)
+            with self._memo_lock:
+                unseen = evalpipe.plan_rows(self._memo, keys, claimed)
+                if self._screen is None or not unseen:
+                    return evalpipe.PoolPlan(keys=keys, train=unseen)
+                must = frozenset(k for k in unseen if k in self._deferred)
+                if force_train is not None:
+                    must = must | frozenset(k for k in unseen if k in force_train)
+                ctx = evalpipe.ScreenContext(
+                    masks=masks,
+                    cats=cats,
+                    keys=keys,
+                    unseen=dict(unseen),
+                    memo=self._memo,
+                    must_train=must,
+                    final=self._screen_final(),
+                )
+                decision = evalpipe.resolve_decision(ctx, self._screen(ctx))
+                self._deferred.update(decision.deferred)
+                return evalpipe.PoolPlan(
+                    keys=keys,
+                    train=decision.train,
+                    deferred={k: unseen[k] for k in decision.deferred},
+                    screen_info=decision.telemetry,
+                )
 
     def commit_pool(
         self, plan: "evalpipe.PoolPlan", objs: np.ndarray | None
@@ -756,18 +768,19 @@ class NSGA2:
         cross-engine overlap lives in :meth:`IslandNSGA2._run_async`.
         """
         if self.pop is None:
-            masks, cats = self.setup_begin()
-            self.setup_commit(
-                self.dispatch_pool(masks, cats, dispatch_evaluate)()
-            )
+            with spans.span("nsga2.setup"):
+                masks, cats = self.setup_begin()
+                self.setup_commit(
+                    self.dispatch_pool(masks, cats, dispatch_evaluate)()
+                )
             if checkpoint_hook is not None:
                 checkpoint_hook(self, 0)
         for _ in range(self.gen, self.cfg.n_generations):
             allm, allc = self.step_begin()
-            t_eval = time.perf_counter()
-            resolve = self.dispatch_pool(allm, allc, dispatch_evaluate)
-            allo = resolve()
-            self.step_commit(allo, time.perf_counter() - t_eval)
+            with spans.span("nsga2.evaluate") as ev:
+                resolve = self.dispatch_pool(allm, allc, dispatch_evaluate)
+                allo = resolve()
+            self.step_commit(allo, ev.dur)
             if checkpoint_hook is not None:
                 checkpoint_hook(self, self.gen)
         return self.result()
@@ -898,6 +911,9 @@ class NSGA2:
         self.n_memo_hits = int(meta["n_memo_hits"])
         self.n_deferred = int(meta.get("n_deferred", 0))
         self._pending = None
+        if self._gen_span is not None:  # a generation a fault interrupted
+            self._gen_span.__exit__(None, None, None)
+            self._gen_span = None
         if not keep_memo:
             self._memo.clear()
             if "memo_keys" in arrays:
@@ -1474,8 +1490,8 @@ class IslandNSGA2:
                 isl.step_commit(allo, share)
                 for isl, allo in zip(self.islands, allos)
             ]
-            # same correction for gen_s: each island's _t_gen spans the
-            # whole K-island wave (every begin phase, the shared program,
+            # same correction for gen_s: each island's generation span
+            # covers the whole K-island wave (every begin phase, the shared program,
             # the earlier commits), so the raw per-island number is ~K x
             # the truth and their sum ~K^2 x.  Overwrite with an equal
             # share of the measured wave so the aggregated history's
@@ -1551,6 +1567,7 @@ class IslandNSGA2:
                 t0 = time.perf_counter()
                 allo = resolve()  # blocks iff this batch is still in flight
                 recs.append(isl.step_commit(allo, time.perf_counter() - t0))
+            # a perf_counter pair, not the spans: one wave is spread over K islands
             wave_share = (time.perf_counter() - t_wave) / len(self.islands)
             for rec in recs:
                 rec["gen_s"] = round(wave_share, 4)

@@ -22,7 +22,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.core import adc
+from repro.core import adc, spans
 
 __all__ = [
     "quantize_pow2",
@@ -229,21 +229,27 @@ def mlp_forward(
 
     start = 0
     if mask is None:
-        h = quantize_uniform(jnp.clip(x, 0.0, 1.0), cfg.adc_bits)
+        with spans.scope("adc"):
+            h = quantize_uniform(jnp.clip(x, 0.0, 1.0), cfg.adc_bits)
     elif use_fused:
         from repro.kernels import fused_qat  # deferred: kernels -> core is one-way
 
-        w0 = layer_w(0)
-        h = fused_qat.fused_qat_first_layer(x, mask, w0, params["b0"], cfg.adc_bits)
+        with spans.scope("layer"):
+            w0 = layer_w(0)
+        with spans.scope("adc"):
+            h = fused_qat.fused_qat_first_layer(x, mask, w0, params["b0"], cfg.adc_bits)
         if n_layers > 1:
-            h = hidden_act(h, 0)
+            with spans.scope("layer"):
+                h = hidden_act(h, 0)
         start = 1
     else:
-        h = adc.quantize_pruned_ste(x, mask, cfg.adc_bits)
+        with spans.scope("adc"):
+            h = adc.quantize_pruned_ste(x, mask, cfg.adc_bits)
     for i in range(start, n_layers):
-        h = h @ layer_w(i) + params[f"b{i}"]
-        if i < n_layers - 1:
-            h = hidden_act(h, i)
+        with spans.scope("layer"):
+            h = h @ layer_w(i) + params[f"b{i}"]
+            if i < n_layers - 1:
+                h = hidden_act(h, i)
     return h
 
 

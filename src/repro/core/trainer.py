@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import chromosome, qat
+from repro.core import chromosome, qat, spans
 from repro.parallel import sharding as shd
 
 __all__ = ["EvalConfig", "make_population_evaluator", "make_island_evaluator"]
@@ -137,32 +137,62 @@ def _make_train_one(
                 p, xb, mlp_cfg, mask, wb, ab, use_fused=cfg.use_fused_kernel,
                 act_sel=act_sel, layer_weight_bits=layer_wb,
             )
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            ce = -jnp.take_along_axis(logp, yb[:, None], axis=-1)[:, 0]
-            return jnp.sum(w * ce) / jnp.maximum(jnp.sum(w), 1.0)
+            with spans.scope("loss"):
+                logp = jax.nn.log_softmax(logits, axis=-1)
+                ce = -jnp.take_along_axis(logp, yb[:, None], axis=-1)[:, 0]
+                return jnp.sum(w * ce) / jnp.maximum(jnp.sum(w), 1.0)
 
         def step(carry, t):
             p, v = carry
             k = jax.random.fold_in(key, t)
             idx = jax.random.randint(k, (cfg.max_batch,), 0, n_train)
-            xb, yb = X_tr[idx], y_tr[idx]
+            with spans.scope("gather"):
+                xb, yb = X_tr[idx], y_tr[idx]
             w = (jnp.arange(cfg.max_batch) < bs).astype(jnp.float32)
             grads = jax.grad(loss_fn)(p, xb, yb, w)
             frac = jnp.minimum(t.astype(jnp.float32) / budget, 1.0)
             lr_t = lr * 0.5 * (1.0 + jnp.cos(jnp.pi * frac))
             active = (t.astype(jnp.float32) < budget).astype(jnp.float32)
-            v = jax.tree.map(lambda vi, g: cfg.momentum * vi - lr_t * g, v, grads)
-            p = jax.tree.map(lambda pi, vi: pi + active * vi, p, v)
+            with spans.scope("sgd"):
+                v = jax.tree.map(lambda vi, g: cfg.momentum * vi - lr_t * g, v, grads)
+                p = jax.tree.map(lambda pi, vi: pi + active * vi, p, v)
             return (p, v), None
 
         (params, _), _ = jax.lax.scan(step, (params, velocity), jnp.arange(cfg.max_steps))
-        logits = qat.mlp_forward(
-            params, X_te, mlp_cfg, mask, wb, ab, use_fused=cfg.use_fused_kernel,
-            act_sel=act_sel, layer_weight_bits=layer_wb,
-        )
-        return qat.accuracy(logits, y_te)
+        with spans.scope("test"):
+            logits = qat.mlp_forward(
+                params, X_te, mlp_cfg, mask, wb, ab, use_fused=cfg.use_fused_kernel,
+                act_sel=act_sel, layer_weight_bits=layer_wb,
+            )
+            return qat.accuracy(logits, y_te)
 
     return train_one
+
+
+def _useful_row_steps(bs, ep, n_train: int, cfg: EvalConfig) -> int:
+    """Row-steps ``train_one`` trains for rows with batch sizes ``bs`` and
+    epochs ``ep``: the steps ``t < budget`` (its budget, in float32 as it
+    computes it) times the samples a step weights."""
+    bs = np.asarray(bs, np.float32)
+    ep = np.asarray(ep, np.float32)
+    budget = np.minimum(
+        np.maximum(ep * np.ceil(np.float32(n_train) / bs) * np.float32(cfg.step_scale), 1.0),
+        np.float32(cfg.max_steps),
+    )
+    return int((np.ceil(budget).astype(np.int64) * np.minimum(bs, cfg.max_batch)).sum())
+
+
+def _count_rows(batches, computed: int, n_train: int, cfg: EvalConfig) -> None:
+    """Counters of one evaluator call: the real rows of its ``batches``
+    (each a ``(bs, ep)`` pair of row arrays) and the ``computed`` rows its
+    program scans, padding included."""
+    n = sum(len(bs) for bs, _ in batches)
+    spans.count("trainer.rows", n)
+    spans.count("trainer.padded_rows", computed - n)
+    if spans.is_recording():
+        useful = sum(_useful_row_steps(bs, ep, n_train, cfg) for bs, ep in batches)
+        spans.count("trainer.useful_row_steps", useful)
+        spans.count("trainer.scanned_row_steps", computed * cfg.max_steps * cfg.max_batch)
 
 
 def make_population_evaluator(
@@ -197,6 +227,7 @@ def make_population_evaluator(
     unchanged.
     """
     train_one = _make_train_one(X_tr, y_tr, X_te, y_te, mlp_cfg, cfg)
+    n_train = np.shape(X_tr)[0]
 
     pop_mesh = shd.population_mesh(n_devices) if mesh is None else mesh
     rules = shd.population_rules()
@@ -208,6 +239,7 @@ def make_population_evaluator(
 
     @jax.jit
     def _evaluate_padded(*args):
+        spans.count("trainer.program_builds", 1)  # the body runs once per trace
         return jax.vmap(train_one)(*args)
 
     def _shard(arr):
@@ -220,15 +252,21 @@ def make_population_evaluator(
     def evaluate(*args):
         P = np.shape(args[0])[0]
         bucket = -(-P // granule) * granule
-        if bucket == P:
-            # device arrays, even ones a caller placed on its own mesh with
-            # Explicit axes, move device-to-device onto this evaluator's
-            # Auto mesh: no host round-trip, and one program for any caller
-            return _evaluate_padded(*(_shard(a) for a in args))
-        # edge-replicate: padded rows are valid chromosomes, just unused
-        args = [np.asarray(a) for a in args]
-        args = [np.concatenate([a, np.repeat(a[-1:], bucket - P, 0)]) for a in args]
-        return _evaluate_padded(*(_shard(a) for a in args))[:P]
+        with spans.span("trainer.call", rows=P, bucket=bucket):
+            _count_rows([(args[3], args[4])], bucket, n_train, cfg)
+            with spans.span("trainer.input"):
+                if bucket != P:
+                    # edge-replicate: padded rows are valid chromosomes, just unused
+                    args = [np.asarray(a) for a in args]
+                    args = [np.concatenate([a, np.repeat(a[-1:], bucket - P, 0)])
+                            for a in args]
+                # device arrays, even ones a caller placed on its own mesh with
+                # Explicit axes, move device-to-device onto this evaluator's
+                # Auto mesh: no host round-trip, and one program for any caller
+                placed = [_shard(a) for a in args]
+            with spans.span("trainer.dispatch"):
+                acc = _evaluate_padded(*placed)
+            return acc if bucket == P else acc[:P]
 
     def dispatch(*args):
         """Launch the batch's program now; block in the returned resolve.
@@ -299,6 +337,7 @@ def make_island_evaluator(
     if num_islands < 1:
         raise ValueError(f"num_islands must be >= 1, got {num_islands}")
     train_one = _make_train_one(X_tr, y_tr, X_te, y_te, mlp_cfg, cfg)
+    n_train = np.shape(X_tr)[0]
 
     isl_mesh = shd.island_mesh(num_islands, n_devices) if mesh is None else mesh
     rules = shd.island_rules()
@@ -309,6 +348,7 @@ def make_island_evaluator(
 
     @jax.jit
     def _evaluate_stacked(*args):
+        spans.count("trainer.program_builds", 1)  # the body runs once per trace
         return jax.vmap(jax.vmap(train_one))(*args)
 
     def _shard(arr):
@@ -333,6 +373,15 @@ def make_island_evaluator(
         if not any(sizes):
             return None, sizes
         bucket = -(-max(sizes) // granule) * granule
+        with spans.span("trainer.call", rows=sum(sizes), bucket=bucket):
+            _count_rows([(b[3], b[4]) for b in batches], num_islands * bucket, n_train, cfg)
+            with spans.span("trainer.input"):
+                stacked = _stack(batches, sizes, bucket)
+            with spans.span("trainer.dispatch"):
+                return _evaluate_stacked(*stacked), sizes
+
+    def _stack(batches, sizes, bucket):
+        """Pad every island's rows to ``bucket``, stack and place them."""
         # filler for zero-row islands: any valid chromosome, results unused
         filler = next(
             [np.asarray(a)[:1] for a in b]
@@ -352,7 +401,7 @@ def make_island_evaluator(
                         )
                 rows.append(a)
             stacked.append(_shard(np.stack(rows)))
-        return _evaluate_stacked(*stacked), sizes
+        return stacked
 
     def _split(accs, sizes):
         """Slice the padded (K, B) result back into per-island rows."""
