@@ -11,9 +11,12 @@ that kept level (so downstream arithmetic keeps the uniform value grid
 
 Two equivalent implementations are provided:
 
-* :func:`quantize_pruned`   — fast vectorised quantizer (searchsorted over
-  the kept-threshold table).  This is what training uses; it is also the
-  reference oracle for the Pallas kernel in ``repro.kernels.pruned_quant``.
+* :func:`quantize_pruned`   — fast vectorised quantizer: the comparator
+  bank and priority encoder as a compare-and-max over the level axis,
+  ``max_i where(mask[c, i] & (x >= i·vref/2^N), i, 0)``.  This is what
+  training uses; it is also the reference oracle for the Pallas kernels in
+  ``repro.kernels.pruned_quant`` and ``repro.kernels.fused_qat``, which
+  share its formula.
 * :func:`circuit_simulate`  — bit-exact gate-level simulation of the pruned
   flash ADC (comparator bank -> thermometer code -> level-select ANDs ->
   OR-tree encoder).  Used only by property tests to prove the fast path is
@@ -93,17 +96,15 @@ def kept_thresholds(mask: jnp.ndarray, n_bits: int, vref: float = 1.0) -> jnp.nd
     return jnp.sort(thr, axis=-1)
 
 
-def _count_below(x: jnp.ndarray, thr: jnp.ndarray) -> jnp.ndarray:
-    """Number of kept thresholds <= x  (the comparator-bank popcount)."""
-    # x: (..., C), thr: (C, T) -> broadcast compare, sum over T.
-    return jnp.sum(x[..., None] >= thr, axis=-1).astype(jnp.int32)
-
-
 @partial(jax.jit, static_argnames=("n_bits",))
 def quantize_pruned(
     x: jnp.ndarray, mask: jnp.ndarray, n_bits: int, vref: float = 1.0
 ) -> jnp.ndarray:
     """Quantize ``x`` through per-channel pruned flash ADCs.
+
+    The comparator bank plus priority encoder: the output is the largest
+    kept level whose threshold ``x`` reaches, a masked max over the level
+    axis (no sort, no gather).
 
     Args:
       x:    (..., C) analog inputs in [0, vref).
@@ -114,27 +115,10 @@ def quantize_pruned(
     mask = force_level0(mask)
     n = 1 << n_bits
     x = jnp.clip(x, 0.0, vref * (1.0 - 0.5 / n))
-    thr = kept_thresholds(mask, n_bits, vref)  # (C, n-1) sorted, inf-padded
-    rank = _count_below(x, thr)  # how many kept comparators fire
-    # rank r means the r-th kept threshold (1-indexed) was the last to fire;
-    # map back to the original level id of that threshold.
+    lvl = jnp.arange(1, n, dtype=jnp.float32) * (vref / n)
     lvl_ids = jnp.arange(1, n, dtype=jnp.int32)
-    keep = mask[..., 1:]
-    # kept level ids compacted to the front, zeros after (rank==0 -> level 0)
-    order = jnp.argsort(jnp.where(keep, lvl_ids, jnp.iinfo(jnp.int32).max), axis=-1)
-    compact = jnp.where(
-        jnp.arange(n - 1) < jnp.sum(keep, axis=-1, keepdims=True),
-        jnp.take_along_axis(jnp.broadcast_to(lvl_ids, keep.shape), order, axis=-1),
-        0,
-    )  # (C, n-1): compact[c, r-1] = original id of r-th kept level
-    padded = jnp.concatenate(
-        [jnp.zeros(compact.shape[:-1] + (1,), compact.dtype), compact], axis=-1
-    )  # (C, n): padded[c, r] for rank r (0 -> level 0)
-    return jnp.take_along_axis(
-        jnp.broadcast_to(padded, x.shape[:-1] + padded.shape),
-        rank[..., None],
-        axis=-1,
-    )[..., 0]
+    fired = (x[..., None] >= lvl) & mask[..., 1:]  # kept comparators that fire
+    return jnp.max(jnp.where(fired, lvl_ids, 0), axis=-1)
 
 
 def quantize_pruned_ste(

@@ -1,6 +1,6 @@
 """Pure-jnp oracle for the pruned_quant kernel.
 
-Independent of both the kernel and the fast searchsorted path in
+Independent of both the kernel and the compare-and-max path in
 ``core.adc`` (the tests cross-check all three).
 """
 

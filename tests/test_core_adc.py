@@ -35,7 +35,7 @@ def masks_strategy(n_channels=2):
     ),
 )
 def test_fast_quantizer_equals_circuit(mask, x):
-    """The searchsorted quantizer IS the gate-level pruned flash ADC."""
+    """The compare-and-max quantizer IS the gate-level pruned flash ADC."""
     fast = np.asarray(adc.quantize_pruned(jnp.asarray(x), jnp.asarray(mask), N_BITS))
     circ = adc.circuit_simulate(x, mask, N_BITS)
     np.testing.assert_array_equal(fast, circ)
