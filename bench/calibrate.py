@@ -98,15 +98,14 @@ def main(argv=None) -> int:
         memo = {}
         line = {"seed": seed, "window_s": win["window_s"], "rows": win["rows"],
                 "sampled": {k: len(v) for k, v in picks.items()},
-                "program": check.compare(cell.config, win["groups"], picks, block, memo=memo)}
+                "program": check.compare(cell, win["groups"], picks, block, memo=memo)}
         if win["fronts"]:
-            line["program"].update(check.front_numbers(cell.config, win["groups"], win["fronts"]))
+            line["program"].update(check.front_numbers(cell, win["groups"], win["fronts"]))
         for name, planted in variants.items():
-            line[name] = check.compare(cell.config, win["groups"], picks, block, memo=memo,
-                                       **planted)
+            line[name] = check.compare(cell, win["groups"], picks, block, memo=memo, **planted)
         if args.faults:
             line["altered"] = check.compare(
-                cell.config, win["groups"], picks, block, memo=memo,
+                cell, win["groups"], picks, block, memo=memo,
                 answers=lambda g: np.concatenate([_other_row(a) for a in g.acc]))
         print(json.dumps(line), flush=True)
     for seed in (int(s) for s in args.fresh.split(",") if s):

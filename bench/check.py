@@ -1,12 +1,13 @@
-"""What decides ``correct``: the window's own answers against the reference.
+"""What decides ``correct``: a plain reference checks the window's own answers.
 
 Every row the window trained is recorded with the accuracy the program
 answered (``window.Group``).  Once the window has closed, two samples of
 those rows are drawn from ``--seed``: one over all rows, one among the
 rows with the smallest learning-rate gene.  Both are trained again by the
-plain float32 reference (``reference.make_qat_reference``) on the same data
-with the same seeds, and each row's gap is the distance, in test samples,
-between the program's accuracy and the reference's.
+plain float32 reference of the configuration's reference module
+(``make_qat_reference`` of ``cell.ref``) on the same data with the same
+seeds, and each row's gap is the distance, in test samples, between the
+program's accuracy and the reference's.
 
 * ``gap_mean``: the mean gap over all sampled rows, whatever their genes,
   so every learning rate, batch size and epoch count the window trains.
@@ -21,16 +22,17 @@ between the program's accuracy and the reference's.
   smallest learning rate the updates are smallest: a control that keeps
   its parameters in bfloat16 loses them, and so does a step that trains on
   part of its minibatch, while the program stays within about a sample of
-  the reference.  The mean swings with the few rows that flip by ten
-  samples or more; the share counts each such row once, so it is the
-  steadier of the two from seed to seed.
+  the float32 reference run.  The mean swings with the few rows that flip
+  by ten samples or more; the share counts each such row once, so it is
+  the steadier of the two from seed to seed.
 
 Each cell's limits file says which numbers it holds.
 
 For searches, the assembly of each Pareto front is checked exactly: every
 front member's accuracy is one the program answered for that genome in that
-search, its area is the gate-by-gate reference area, and no member
-dominates another.
+search (the member's rows as the reference module decodes them, every
+column but the training seed), its area is the area the reference module
+gives, and no member dominates another.
 
 The limits of a cell are in ``limits/<cell>.json``, with the readings
 they were set from.
@@ -41,12 +43,13 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from bench import reference, work
+from bench import work
 
 
 def sample(groups, n: int, seed: int, lr: float | None = None, stream: int = 7) -> list:
     """``n`` (group, row) pairs drawn from ``seed`` over the answered rows,
-    or over those with learning rate ``lr`` (all of them when fewer)."""
+    or over those with learning rate ``lr`` (all of them when fewer); a
+    row's learning rate is its sixth column."""
     index = [(g, r) for g, grp in enumerate(groups)
              for r in np.flatnonzero(np.concatenate([rows[5] for rows in grp.rows]) == lr
                                      if lr is not None else
@@ -64,17 +67,17 @@ def samples(cell, win: dict, seed: int) -> dict:
             "lowlr": sample(win["groups"], n, seed, lr)}
 
 
-def reference_answers(cfg: dict, groups, picks, block: int, dtype=jnp.float32,
+def reference_answers(cell, groups, picks, block: int, dtype=jnp.float32,
                       batch_share: float = 1.0, rows_fn=None):
     """(program accuracy, reference accuracy, n_test) of each picked row.
     The reference runs once per group on a block of ``block`` rows (padded
     with the group's last row), so one compiled program serves every group.
     ``dtype``, ``batch_share`` and ``rows_fn`` (applied to the rows the
     reference trains) plant the control and the faults in its place."""
-    n_train, n_test = work.split_sizes(cfg["dataset"])
+    n_train, n_test = work.split_sizes(cell.config["dataset"])
     if not picks:
         return np.zeros(0), np.zeros(0), n_test
-    fn = reference.make_qat_reference(cfg, n_train, dtype, batch_share)
+    fn = cell.ref.make_qat_reference(cell.config, n_train, dtype, batch_share)
     prog, ref = [], []
     for g in sorted({g for g, _ in picks}):
         rows, acc = groups[g].arrays()
@@ -101,7 +104,7 @@ NUMBERS = {
 }
 
 
-def compare(cfg: dict, groups, picks: dict, block: int, answers=None, memo=None,
+def compare(cell, groups, picks: dict, block: int, answers=None, memo=None,
             **planted) -> dict:
     """The numbers of each sample in ``picks`` for the program's answers
     (or for ``answers(group)``, a group's answers altered, put in their
@@ -113,39 +116,38 @@ def compare(cfg: dict, groups, picks: dict, block: int, answers=None, memo=None,
     out = {}
     for name, sel in picks.items():
         if name not in memo:
-            memo[name] = reference_answers(cfg, groups, sel, block)
+            memo[name] = reference_answers(cell, groups, sel, block)
         prog, ref, n_test = memo[name]
         if answers is not None and sel:
             prog = np.asarray([answers(groups[g])[r] for g, r in sel], np.float32)
         if planted:
-            _, prog, _ = reference_answers(cfg, groups, sel, block, **planted)
+            _, prog, _ = reference_answers(cell, groups, sel, block, **planted)
         out.update(NUMBERS[name](gaps(prog, ref, n_test)))
     return out
 
 
-def front_numbers(cfg: dict, groups, fronts) -> dict:
+def front_numbers(cell, groups, fronts) -> dict:
     """Exact checks of each search's front assembly."""
     by_seed = {g.eval_seed: g for g in groups}
-    genes = cfg["genes"]
+    cfg = cell.config
     unmatched = dominated = 0
     area_gap = 0.0
     for f in fronts:
         rows, acc = by_seed[f["seed"]].arrays()
+        masks = np.asarray(f["masks"], bool).reshape(len(f["acc"]), -1)
         cats = np.asarray(f["cats"], np.int64)
-        vals = (np.asarray(genes["weight_bits"], np.float32)[cats[:, 0]],
-                np.asarray(genes["act_bits"], np.float32)[cats[:, 1]],
-                np.asarray(genes["batch_size"], np.int32)[cats[:, 2]],
-                np.asarray(genes["epochs"], np.int32)[cats[:, 3]],
-                np.asarray(genes["lr"], np.float32)[cats[:, 4]])
+        # the members' rows but the training seed, which is the crc32 of
+        # the genome's bytes and not of its decoded level masks
+        members = cell.ref.decode(masks, cats, cfg)[:-1]
         # the search reports 1 - (1 - acc) with the miss taken in float32
         reported = 1.0 - (np.float32(1.0) - acc).astype(np.float64)
         for m in range(len(f["acc"])):
-            same = np.all(rows[0] == f["masks"][m], axis=(1, 2))
-            for col, v in zip(rows[1:6], vals):
-                same &= col == v[m]
-            if not np.any(same & (reported == f["acc"][m])):
+            same = reported == f["acc"][m]
+            for col, v in zip(rows, members):
+                same &= np.all((col == v[m]).reshape(len(col), -1), axis=1)
+            if not np.any(same):
                 unmatched += 1
-        ref_area = reference.adc_area(f["masks"], cfg["adc_bits"], cfg["area_gates"])
+        ref_area = cell.ref.area(masks, cats, cfg)
         area_gap = max(area_gap, float(np.max(np.abs(f["area"] - ref_area) / ref_area)))
         obj = np.stack([1.0 - np.asarray(f["acc"]), np.asarray(f["area"])], axis=1)
         le = np.all(obj[:, None] <= obj[None], axis=-1)
@@ -160,7 +162,7 @@ def numbers(cell, win: dict, seed: int) -> dict:
     cell's limits hold, and the front checks."""
     picks = {k: v for k, v in samples(cell, win, seed).items()
              if set(NUMBERS[k](np.zeros(0))) & set(cell.limits)}
-    out = compare(cell.config, win["groups"], picks, cell.traffic["reference_rows"])
+    out = compare(cell, win["groups"], picks, cell.traffic["reference_rows"])
     if win["fronts"]:
-        out.update(front_numbers(cell.config, win["groups"], win["fronts"]))
+        out.update(front_numbers(cell, win["groups"], win["fronts"]))
     return out

@@ -16,7 +16,7 @@ import time
 import jax
 import numpy as np
 
-from bench import genomes, reference, window
+from bench import window
 
 
 class Tap:
@@ -86,7 +86,7 @@ class Tap:
 
 class Driver:
     def __init__(self, cell):
-        self.cfg, self.traffic = cell.config, cell.traffic
+        self.cfg, self.traffic, self.ref = cell.config, cell.traffic, cell.ref
 
     def codesign_config(self, search_seed: int):
         from repro.core import codesign
@@ -130,7 +130,7 @@ class Driver:
         for b in range(granule, top + 1, granule):
             if stacked and b > granule:
                 break  # the population program only scores the 4 baseline rows
-            rows = reference.decode(*genomes.draw(rng, b, self.cfg), self.cfg)
+            rows = self.ref.decode(*self.ref.draw(rng, b, self.cfg), self.cfg)
             compiled = pop.program.lower(*(pop.shard_fn(a) for a in rows)).compile()
             out = jax.device_put(np.zeros(b, np.float32), compiled.output_shardings)
             for p in range(max(b - granule + 1, 1), b):
@@ -140,7 +140,7 @@ class Driver:
                                                 num_islands=cc.num_islands)
             for b in range(isl.granule, -(-cc.pop_size // isl.granule) * isl.granule + 1,
                            isl.granule):
-                rows = reference.decode(*genomes.draw(rng, b, self.cfg), self.cfg)
+                rows = self.ref.decode(*self.ref.draw(rng, b, self.cfg), self.cfg)
                 stacked_rows = [isl.shard_fn(np.stack([a] * cc.num_islands)) for a in rows]
                 isl.program.lower(*stacked_rows).compile()
 
@@ -168,7 +168,7 @@ class Driver:
                 i += 1
         ds = self.cfg["dataset"]
         for g in tap.groups.values():
-            g.data = reference.split(*reference.load_dataset(ds), ds["train_frac"], g.eval_seed)
+            g.data = self.ref.split(*self.ref.load_dataset(ds), ds["train_frac"], g.eval_seed)
         return {
             "window_s": sum(r["wall_s"] for r in searches),
             "searches": searches,

@@ -1,8 +1,9 @@
 """Waves of fresh genomes through the population QAT program.
 
 Blocking calls of ``core.trainer.make_population_evaluator``'s evaluator on
-waves of ``wave_rows`` genomes (``genomes.draw``), host rows in and
-accuracies to host, with the data split and trainer seed of the traffic.
+waves of ``wave_rows`` genomes (``draw`` of the configuration's reference
+module), host rows in and accuracies to host, with the data split and
+trainer seed of the traffic.
 No wave starts after ``seconds``; the window ends when the last returns.
 """
 
@@ -12,12 +13,12 @@ import time
 
 import numpy as np
 
-from bench import genomes, reference, window, work
+from bench import program_trace, window, work
 
 
 class Driver:
     def __init__(self, cell):
-        self.cfg, self.traffic = cell.config, cell.traffic
+        self.cfg, self.traffic, self.ref = cell.config, cell.traffic, cell.ref
         self.ev = None
 
     def prepare(self) -> None:
@@ -26,8 +27,8 @@ class Driver:
 
         window.trainer_check(self.cfg)
         tr, cfg = self.traffic, self.cfg
-        self.data = reference.split(*reference.load_dataset(cfg["dataset"]),
-                                    cfg["dataset"]["train_frac"], tr["split_seed"])
+        self.data = self.ref.split(*self.ref.load_dataset(cfg["dataset"]),
+                                   cfg["dataset"]["train_frac"], tr["split_seed"])
         t = cfg["trainer"]
         self.ev = trainer.make_population_evaluator(
             *self.data, window.mlp(cfg),
@@ -36,13 +37,13 @@ class Driver:
                                seed=tr["eval_seed"], genome_axes=tuple(cfg["genome_axes"])),
         )
         rng = np.random.default_rng([2**32 - 1])
-        np.asarray(self.ev(*reference.decode(*genomes.draw(rng, tr["wave_rows"], cfg), cfg)))
+        np.asarray(self.ev(*self.ref.decode(*self.ref.draw(rng, tr["wave_rows"], cfg), cfg)))
 
     def draw(self, seed: int) -> None:
         """The window's waves, made from ``seed``."""
         rng = np.random.default_rng(seed)
-        self.pool = [reference.decode(*genomes.draw(rng, self.traffic["wave_rows"], self.cfg),
-                                      self.cfg) for _ in range(self.traffic["pool_waves"])]
+        self.pool = [self.ref.decode(*self.ref.draw(rng, self.traffic["wave_rows"], self.cfg),
+                                     self.cfg) for _ in range(self.traffic["pool_waves"])]
 
     def window(self, seconds: float, prof: window.Profiler | None) -> dict:
         group = window.Group(self.traffic["eval_seed"], self.data)
@@ -74,6 +75,10 @@ class Driver:
             "fronts": [],
             "program": "_evaluate_padded",
         }
+
+    def op_names(self) -> dict:
+        """The op_name of each operation of the waves' program, by name."""
+        return program_trace.op_names_from_hlo(program_trace.wave_program_text(self))
 
     def release(self) -> None:
         self.ev = None

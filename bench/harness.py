@@ -2,25 +2,31 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell names its configuration (``configs/<config>.json``) and traffic
-mix (``traffic/<traffic>.json``), which names its driver
+The cell names its configuration (``configs/<config>.json``), which names
+its plain reference module (``references/<reference>.py``: the genomes,
+their rows and data, the reference training run and area), and its
+traffic mix (``traffic/<traffic>.json``), which names its driver
 (``drivers/<driver>.py``); each metric, end-to-end or per-layer, is read by
-``metrics/<metric>.py`` and its limits are in ``limits/<cell>.json``.  A
+``metrics/<metric>.py`` (``read(rec)``: the number, or None when the run
+has nothing to read; a roofline's reader also gives ``bound(rec)``, what
+bounds the least time) and its limits are in ``limits/<cell>.json``.  A
 run loads, warms up every program the window can call, measures for
-``--seconds`` (``--trace 1``: with a profiler trace of the first search or
-waves, and reports the per-layer metrics instead of the end-to-end ones),
-checks the window's answers against the reference (``check``), and prints
-one JSON line last.  Without a TPU, or with fewer
-chips than the cell asks for, it exits non-zero and prints no result.
+``--seconds``, checks the window's answers against the reference module
+(``check``), and prints one JSON line last.  With ``--trace 1`` the window
+runs with a profiler trace of its first search or waves and with the
+program's spans and counters recorded (``repro.core.spans``), and the run
+reports the per-layer metrics instead of the end-to-end ones.  Without a
+TPU, or with fewer chips than the cell asks for, it exits non-zero and
+prints no result.
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import gc
 import glob
-import importlib.util
 import json
 import os
 import sys
@@ -42,11 +48,15 @@ class Cell:
     end_to_end: list
     per_layer: list
     root: Path
+    ref: object  # the configuration's reference module
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
     """The cell ``name`` of ``root/BENCHMARK.json`` with its configuration,
-    traffic, limits and metrics, each found by name under ``root``."""
+    its reference module, traffic, limits and metrics, each found by name
+    under ``root``."""
+    from bench import window
+
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -57,12 +67,13 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"]
                  if (name in m["workloads"] if "workloads" in m else m["moves"] in names)]
+    config = json.loads((root / conf["file"]).read_text())
     return Cell(
-        name=name, chips=w["chips"],
-        config=json.loads((root / conf["file"]).read_text()),
+        name=name, chips=w["chips"], config=config,
         traffic=json.loads((root / "bench" / "traffic" / f"{w['traffic']}.json").read_text()),
         limits=json.loads((root / "bench" / "limits" / f"{name}.json").read_text())["limits"],
         end_to_end=e2e, per_layer=per_layer, root=root,
+        ref=window.load_file(root, "references", config["reference"]),
     )
 
 
@@ -137,25 +148,13 @@ class CompileLog:
                 "cache_misses": ev.count("/jax/compilation_cache/cache_misses")}
 
 
-def metric_reader(root: Path, name: str):
-    """The module of ``metrics/<name>.py``: ``read(rec)`` gives the number
-    or None when the run has nothing to read; a roofline reader also gives
-    ``bound(rec)``, what bounds the least time."""
-    if not (root / "bench" / "metrics" / f"{name}.py").is_file():
-        raise ValueError(f"no reader for metric {name!r} in bench/metrics")
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def run(cell: Cell, seed: int, seconds: float, trace: bool, device: dict,
         t_start: float) -> dict:
     """Set-up, window, check; returns the result line's object."""
     import jax
 
-    from bench import check, trace_reduce, window
+    from bench import check, program_trace, trace_reduce, window
+    from repro.core import spans
 
     enable_compile_cache()
     log = CompileLog()
@@ -167,10 +166,12 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, device: dict,
     with tempfile.TemporaryDirectory() as tdir:
         prof = window.Profiler(tdir) if trace else None
         t0 = time.perf_counter()
-        win = driver.window(seconds, prof)
+        with spans.recording() if trace else contextlib.nullcontext() as program_log:
+            win = driver.window(seconds, prof)
         t1 = time.perf_counter()
         stats = [d.memory_stats() or {} for d in jax.devices()]
         mem = max(s.get("peak_bytes_in_use", 0) for s in stats)
+        op_names = driver.op_names() if trace and hasattr(driver, "op_names") else None
         driver.release()
         gc.collect()
         red = None
@@ -179,15 +180,21 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, device: dict,
             tr = trace_reduce.load_xplane(files[0])
             span = trace_reduce.window_of(tr, "bench.traced")
             red = trace_reduce.reduce(tr, span) if span else None
+            if red:
+                red["idle_spans"] = program_trace.idle_by_span(tr, span, spans.SPANS)
+                if op_names:
+                    red["scope_s"] = program_trace.scope_seconds(
+                        tr, span, spans.SCOPES, win["program"], op_names)
             del tr
     rec = {**win, "setup_s": setup_s, "jit": log.between(t0, t1), "trace": red,
-           "peaks": peak, "chips": cell.chips}
+           "peaks": peak, "chips": cell.chips,
+           "spans": program_log.as_dict() if trace else None}
     nums = check.numbers(cell, win, seed)
     lims = cell.limits
     correct = all(nums[k] <= lims[k] for k in lims)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
-        reader = metric_reader(cell.root, m["name"])
+        reader = window.load_file(cell.root, "metrics", m["name"])
         v = reader.read(rec)
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}

@@ -11,8 +11,10 @@ scale), so that metadata is read from the compiled program's text
 program at a time: two programs may name different operations alike
 (``%fusion.305``).
 
-The harness does not call these yet: see PERF.md, open questions, for the
-edit that puts them in the traced run's result.
+A traced run of the harness records the program's spans and counters and
+puts ``idle_by_span`` under ``idle_spans`` of its trace reduction, and,
+for a driver that names its program's operations (``op_names``),
+``scope_seconds`` under ``scope_s``; the per-layer metrics read them there.
 """
 
 from __future__ import annotations
@@ -124,3 +126,21 @@ def scope_seconds(trace: dict, window: tuple[int, int], scopes, program: str,
             if program in _module_at(modules, start):
                 out[_scope(op_names.get(name, ""), scopes)] += ns / 1e9
     return dict(out)
+
+
+def span_self_ms(log: dict | None, name: str) -> float | None:
+    """Mean self time, in milliseconds, of the spans named ``name`` in a
+    recording (``spans.Log.as_dict()``); None without a recording or such
+    a span."""
+    own = [s["self"] for s in (log or {}).get("spans", ())
+           if s["name"] == name and s["self"] is not None]
+    return 1e3 * sum(own) / len(own) if own else None
+
+
+def useful_share(log: dict | None) -> float | None:
+    """Useful row-steps over the row-steps the QAT scan computed, in percent,
+    from a recording's counters; None without a recording or a scanned
+    row-step."""
+    c = (log or {}).get("counters", {})
+    scanned = c.get("trainer.scanned_row_steps", 0)
+    return 100.0 * c["trainer.useful_row_steps"] / scanned if scanned else None

@@ -1,18 +1,25 @@
-"""Plain references the benchmark compares the program with.
+"""The plain reference of the ADC-only genome (``genome_axes`` ``["adc"]``).
 
-Nothing here imports the program.  Everything is written from the paper's
-description and the configuration file:
+A configuration names its reference module (``"reference": "<name>"``),
+``references/<name>.py``, which the check, the drivers and
+``calibrate.py`` call and nothing else.  Nothing here imports the program.
+Everything is written from the paper's description and the configuration
+file.  The module's contract:
 
 * ``load_dataset`` / ``split``: the synthetic UCI replicas and the 70/30
   stratified split, regenerated from their seeds (the inputs, as weights
   would be for a model);
-* ``decode`` / ``genome_seeds``: the genome → evaluator-row mapping and the
-  crc32 training seed of each genome;
+* ``draw(rng, n, cfg)``: genomes as NSGA-II draws its generation 0;
+* ``decode(masks, cats, cfg)``: genomes to the evaluator's row arrays, in
+  the order level masks, weight bits, activation bits, batch size,
+  epochs, lr, training seed (the crc32 of each genome's bytes); a genome
+  with more axes adds its columns after these, in the trainer's order;
 * ``make_qat_reference``: one genome's quantization-aware training run in
   straightforward ``jax.numpy``, vmapped over a block of genomes, at
   ``default_matmul_precision("highest")`` in float32 (the reference) or in
   bfloat16 throughout (the control);
-* ``adc_area``: the pruned flash-ADC area proxy, counted gate by gate.
+* ``area(masks, cats, cfg)``: each genome's area as the search reports
+  it; here the pruned flash-ADC area proxy, counted gate by gate.
 """
 
 from __future__ import annotations
@@ -84,6 +91,24 @@ def split(x: np.ndarray, y: np.ndarray, train_frac: float, seed: int):
 
 
 # -- genomes --------------------------------------------------------------
+
+CAT_GENES = ("weight_bits", "act_bits", "batch_size", "epochs", "lr")
+
+
+def draw(rng: np.random.Generator, n: int, cfg: dict) -> tuple[np.ndarray, np.ndarray]:
+    """``n`` genomes: (mask genes (n, features * 2^adc_bits) bool, categorical
+    genes (n, 5) int64).  Each genome draws its mask density uniformly from
+    the configuration's ``init_density`` band and keeps each ADC level gene
+    with that probability; every categorical gene is uniform over its
+    table."""
+    n_mask = cfg["dataset"]["n_features"] << cfg["adc_bits"]
+    lo, hi = cfg["init_density"]
+    probs = rng.uniform(lo, hi, size=(n, 1))
+    masks = rng.uniform(size=(n, n_mask)) < probs
+    cats = np.stack(
+        [rng.integers(0, len(cfg["genes"][g]), size=n) for g in CAT_GENES], axis=1
+    ).astype(np.int64)
+    return masks, cats
 
 
 def genome_seeds(masks: np.ndarray, cats: np.ndarray) -> np.ndarray:
@@ -223,6 +248,11 @@ def make_qat_reference(cfg: dict, n_train: int, dtype=jnp.float32, batch_share: 
 
 
 # -- area -----------------------------------------------------------------
+
+
+def area(masks: np.ndarray, cats: np.ndarray, cfg: dict) -> np.ndarray:
+    """Area of each genome: its ADC bank's alone, whatever its other genes."""
+    return adc_area(masks, cfg["adc_bits"], cfg["area_gates"])
 
 
 def adc_area(masks: np.ndarray, adc_bits: int, gates: dict) -> np.ndarray:

@@ -26,8 +26,8 @@ def test_bfloat16_control_fails_the_limit(tmp_path):
     win = driver.window(0.0, None)
     picks = check.samples(cell, win, 2**31 + 11)
     assert len(picks["lowlr"]) == 48 and len(picks["all"]) == 48
-    prog = check.compare(cell.config, win["groups"], picks, 48)
-    ctl = check.compare(cell.config, win["groups"], picks, 48, dtype=jnp.bfloat16)
+    prog = check.compare(cell, win["groups"], picks, 48)
+    ctl = check.compare(cell, win["groups"], picks, 48, dtype=jnp.bfloat16)
     # on the CPU the program's dots are true float32: it reads the reference
     assert jax.default_backend() == "cpu"
     assert all(prog[k] == 0 for k in prog)

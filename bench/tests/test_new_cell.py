@@ -4,6 +4,7 @@ import hashlib
 import json
 
 import jax
+import pytest
 
 from bench import harness
 from tiny import add_cell, tree
@@ -99,3 +100,64 @@ def test_new_traffic_kind_and_metric_are_files_of_their_own(tmp_path):
     assert out["correct"] is True
     assert set(out["metrics"]) == {"setup_s", "qat_rows_per_s", "calls_per_s"}
     assert out["window"]["rows"] == out["window"]["waves"] * 16
+
+
+TALLY_REFERENCE = '''"""The ADC-only genome's reference, counting each call the benchmark makes."""
+
+from pathlib import Path
+
+from bench import window
+
+_base = window.load_file(Path(__file__).resolve().parents[2], "references", "adc_genome")
+CALLS = {}
+
+
+def _tally(name):
+    fn = getattr(_base, name)
+
+    def call(*a, **k):
+        CALLS[name] = CALLS.get(name, 0) + 1
+        return fn(*a, **k)
+    return call
+
+
+load_dataset, split, draw, decode, make_qat_reference, area = map(
+    _tally, ("load_dataset", "split", "draw", "decode", "make_qat_reference", "area"))
+'''
+
+
+def test_new_configuration_brings_its_own_reference(tmp_path):
+    root = tree(tmp_path)
+    before = _digests(root)
+
+    (root / "bench/references/adc_genome_tally.py").write_text(TALLY_REFERENCE)
+    cfg = json.loads((root / "bench/configs/printed-mlp-seeds.json").read_text())
+    cfg["reference"] = "adc_genome_tally"
+    (root / "bench/configs/printed-mlp-seeds-tally.json").write_text(json.dumps(cfg))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({**bench["configs"][0], "name": "printed-mlp-seeds-tally",
+                             "file": "bench/configs/printed-mlp-seeds-tally.json"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    add_cell(root, {"name": "seeds.search_tally", "config": "printed-mlp-seeds-tally",
+                    "traffic": "paper_search", "chips": 1, "why": "tally"}, "seeds.search")
+
+    after = _digests(root)
+    assert {p for p in before if before[p] != after[p]} == {
+        root.joinpath("BENCHMARK.json").relative_to(root)}
+
+    cell = harness.load_cell("seeds.search_tally", root)
+    dev = {"platform": "cpu", "kind": "cpu", "count": len(jax.devices())}
+    out = harness.run(cell, 2**31 + 29, 0.1, False, dev, 0.0)
+    assert out["correct"] is True
+    # the warm-up, the window's data, the check and the front's area all
+    # went through the configuration's own module
+    assert set(cell.ref.CALLS) == {"load_dataset", "split", "draw", "decode",
+                                   "make_qat_reference", "area"}
+
+
+def test_a_configuration_naming_no_such_reference_is_an_error(tmp_path):
+    root = tree(tmp_path)
+    p = root / "bench/configs/printed-mlp-seeds.json"
+    p.write_text(json.dumps({**json.loads(p.read_text()), "reference": "no_such_genome"}))
+    with pytest.raises(ValueError, match="bench/references/no_such_genome.py"):
+        harness.load_cell("seeds.wave1024", root)

@@ -7,7 +7,7 @@ from pathlib import Path
 import jax
 import pytest
 
-from bench import harness, window
+from bench import harness, window, work
 from bench import program_trace as pt
 from bench import trace_reduce as tr
 from tiny import tree
@@ -121,12 +121,38 @@ def test_traced_run_with_the_programs_spans_in(tmp_path):
     cell = harness.load_cell("seeds.wave1024", root)
     out = harness.run(cell, 2**31 + 5, 0.1, True, dev, 0.0)
     assert out["correct"] is True
-    # the driver's waves ran a program whose text carries every scope
+    # the recorded counters are the row-steps the window's genomes train
     driver = window.load_driver(root, cell.traffic["driver"])(cell)
+    driver.draw(2**31 + 5)
+    rows = [driver.pool[i % len(driver.pool)] for i in range(out["window"]["waves"])]
+    n_train = work.split_sizes(cell.config["dataset"])[0]
+    t = cell.config["trainer"]
+    useful = sum((work.useful_steps(r[3], r[4], n_train, t) * r[3]).sum() for r in rows)
+    scanned = out["window"]["rows"] * t["max_steps"] * t["max_batch"]
+    got = out["metrics"]
+    assert got["useful_row_step_share.wave"]["value"] == pytest.approx(100 * useful / scanned,
+                                                                       rel=1e-12)
+    assert got["input_path_ms_per_call.wave"]["value"] > 0
+    # a CPU trace has no device planes: the device's readings are left out
+    assert "qat_adc_share" not in got
+    # the driver's waves ran a program whose text carries every scope
     driver.prepare()
     driver.draw(3)
-    names = pt.op_names_from_hlo(pt.wave_program_text(driver))
+    names = driver.op_names()
     assert {pt._scope(o, SCOPES) for o in names.values()} == set(SCOPES) | {pt.NO_SCOPE}
+
+
+def test_traced_search_counts_repeat_for_a_seed(tmp_path):
+    root = tree(tmp_path)
+    dev = {"platform": "cpu", "kind": "cpu", "count": len(jax.devices())}
+    cell = harness.load_cell("seeds.search", root)
+    runs = [harness.run(cell, 2**31 + 7, 0.1, True, dev, 0.0)["metrics"] for _ in range(2)]
+    for k in ("program_builds_per_search", "useful_row_step_share.search"):
+        assert runs[0][k]["value"] == runs[1][k]["value"]
+    assert runs[0]["program_builds_per_search"]["value"] >= 1
+    assert 0 < runs[0]["useful_row_step_share.search"]["value"] < 100
+    assert runs[0]["input_path_ms_per_call.search"]["value"] > 0
+    assert not {"device_idle_s.build", "device_idle_s.host"} & set(runs[0])
 
 
 @pytest.mark.parametrize("op_name, scope", [

@@ -5,12 +5,17 @@ import json
 import numpy as np
 import pytest
 
-from bench import genomes, reference
+from bench import window
 from tiny import ROOT
 
 
 def _cfg(name):
     return json.loads((ROOT / "bench" / "configs" / f"printed-mlp-{name}.json").read_text())
+
+
+def _ref(cfg):
+    """The reference module the configuration names."""
+    return window.load_file(ROOT, "references", cfg["reference"])
 
 
 @pytest.mark.parametrize("name", ["cardio", "seeds"])
@@ -44,12 +49,13 @@ def test_inputs_genomes_and_area_match_the_program(name):
     from repro.data import uci_synth
 
     cfg = _cfg(name)
+    reference = _ref(cfg)
     x, y = reference.load_dataset(cfg["dataset"])
     px, py, _ = uci_synth.load(cfg["dataset"]["name"])
     assert np.array_equal(x, px) and np.array_equal(y, py)
     for a, b in zip(reference.split(x, y, 0.7, 12), uci_synth.stratified_split(px, py, 0.7, 12)):
         assert np.array_equal(a, b)
-    masks, cats = genomes.draw(np.random.default_rng(3), 16, cfg)
+    masks, cats = reference.draw(np.random.default_rng(3), 16, cfg)
     rows = reference.decode(masks, cats, cfg)
     dec = chromosome.decode_batch(masks, cats, cfg["dataset"]["n_features"], cfg["adc_bits"])
     want = (dec["masks"], dec["weight_bits"], dec["act_bits"], dec["batch_size"],
@@ -57,8 +63,8 @@ def test_inputs_genomes_and_area_match_the_program(name):
     for a, b in zip(rows, want):
         assert a.dtype == b.dtype and np.array_equal(a, b)
     np.testing.assert_allclose(
-        reference.adc_area(rows[0], cfg["adc_bits"], cfg["area_gates"]),
-        area.adc_cost_batch(rows[0], cfg["adc_bits"])[0], rtol=1e-12)
+        reference.area(masks, cats, cfg), area.adc_cost_batch(rows[0], cfg["adc_bits"])[0],
+        rtol=1e-12)
 
 
 def test_reference_trains_as_the_program_does_on_cpu():
@@ -67,10 +73,50 @@ def test_reference_trains_as_the_program_does_on_cpu():
 
     cfg = _cfg("seeds")
     cfg["trainer"]["max_steps"] = 40
+    reference = _ref(cfg)
     data = reference.split(*reference.load_dataset(cfg["dataset"]), 0.7, 4)
-    rows = reference.decode(*genomes.draw(np.random.default_rng(5), 8, cfg), cfg)
+    rows = reference.decode(*reference.draw(np.random.default_rng(5), 8, cfg), cfg)
     ev = trainer.make_population_evaluator(
         *data, qat.MLPConfig(tuple(cfg["layer_sizes"])), trainer.EvalConfig(max_steps=40, seed=4))
     prog = np.asarray(ev(*rows))
     ref = reference.make_qat_reference(cfg, len(data[1]))(*data, 4, *rows)
     np.testing.assert_array_equal(prog, ref)
+
+
+FIXTURE = ROOT / "bench" / "fixtures" / "window_numbers.npz"
+
+
+def _recorded(name: str):
+    """A tiny window of the cell ``name`` recorded on the CPU (its groups,
+    fronts and run seed), with the numbers the benchmark's check gave on it
+    when the ADC-only genome's reference was ``bench/reference.py``: as
+    answered, and with each answer given to the row before it."""
+    z = np.load(FIXTURE)
+    p = f"{name}/"
+    groups, fronts = [], []
+    for i in range(sum(1 for k in z.files if k.startswith(p) and k.endswith("/eval_seed"))):
+        q = f"{p}g{i}/"
+        g = window.Group(int(z[q + "eval_seed"]), tuple(z[q + f"data{k}"] for k in range(4)))
+        g.add(tuple(z[q + f"rows{k}"] for k in range(7)), z[q + "acc"])
+        groups.append(g)
+    for j in range(sum(1 for k in z.files if k.startswith(p) and k.endswith("/area"))):
+        q = f"{p}f{j}/"
+        fronts.append({k: z[q + k] for k in ("masks", "cats", "acc", "area")}
+                      | {"seed": int(z[q + "seed"])})
+    numbers = {kind: {k.rsplit("/", 1)[1]: float(z[k]) for k in z.files
+                      if k.startswith(f"{p}{kind}/")} for kind in ("numbers", "altered")}
+    return {"groups": groups, "fronts": fronts}, int(z[p + "seed"]), numbers
+
+
+@pytest.mark.parametrize("name", ["seeds.search", "seeds.wave1024"])
+def test_check_gives_the_numbers_it_gave_before_the_move(tmp_path, name):
+    from bench import check, harness
+    from tiny import tree
+
+    cell = harness.load_cell(name, tree(tmp_path))
+    win, seed, recorded = _recorded(name)
+    assert check.numbers(cell, win, seed) == recorded["numbers"]
+    for g in win["groups"]:
+        g.acc = [np.roll(a, 1) for a in g.acc]
+    assert check.numbers(cell, win, seed) == recorded["altered"]
+    assert any(v > 0 for v in recorded["altered"].values())

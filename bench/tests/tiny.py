@@ -22,7 +22,7 @@ ISLANDS = {"name": "seeds.islands4", "config": "printed-mlp-seeds", "traffic": "
 def tree(dst: Path) -> Path:
     """``dst`` holding BENCHMARK.json and bench/ with tiny configs and traffic."""
     shutil.copy(ROOT / "BENCHMARK.json", dst / "BENCHMARK.json")
-    for sub in ("configs", "traffic", "limits", "metrics", "drivers"):
+    for sub in ("configs", "references", "traffic", "limits", "metrics", "drivers"):
         shutil.copytree(ROOT / "bench" / sub, dst / "bench" / sub)
     for f in (dst / "bench" / "configs").glob("*.json"):
         cfg = json.loads(f.read_text())

@@ -1,8 +1,13 @@
-"""What every traffic driver shares, and how a driver is found by name.
+"""What every traffic driver shares, and how a file of the benchmark is
+found by name.
 
 A traffic file names its driver (``"driver": "<name>"``); the driver is the
 class ``Driver`` in ``drivers/<name>.py`` under the benchmark's tree, so a
-later cell adds a traffic kind by adding one such file.  A driver has
+later cell adds a traffic kind by adding one such file.  A configuration
+names its plain reference (``"reference": "<name>"``,
+``references/<name>.py``) and a metric is read by ``metrics/<name>.py``,
+found the same way (``load_file``).  A driver takes the configuration's
+genomes, rows and data from its reference module (``cell.ref``), and has
 
 * ``prepare()``: data, evaluators, and the warm-up of every program the
   window can call (set-up);
@@ -10,7 +15,10 @@ later cell adds a traffic kind by adding one such file.  A driver has
 * ``window(seconds, prof)``: drives the program and returns the window's
   record (its rows and answers under ``groups``, which ``check`` compares
   with the reference, and whatever its metric readers read);
-* ``release()``: drops the program's state before the reference runs.
+* ``release()``: drops the program's state before the reference runs;
+* optionally ``op_names()``: each operation of the program its window ran,
+  by name, with its op_name (``program_trace.op_names_from_hlo``), which a
+  traced run reads before ``release``.
 """
 
 from __future__ import annotations
@@ -98,12 +106,18 @@ def mlp(cfg: dict):
     return qat.MLPConfig(tuple(cfg["layer_sizes"]), adc_bits=cfg["adc_bits"])
 
 
-def load_driver(root: Path, name: str):
-    """The class ``Driver`` of ``root/bench/drivers/<name>.py``."""
-    path = Path(root) / "bench" / "drivers" / f"{name}.py"
+def load_file(root: Path, kind: str, name: str):
+    """The module ``root/bench/<kind>/<name>.py``, loaded afresh; an error
+    naming that path when there is no such file."""
+    path = Path(root) / "bench" / kind / f"{name}.py"
     if not path.is_file():
-        raise ValueError(f"no traffic driver {name!r}: {path} does not exist")
-    spec = importlib.util.spec_from_file_location(f"bench_driver_{name}", path)
+        raise ValueError(f"no module {name!r} in bench/{kind}: {path} does not exist")
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.Driver
+    return mod
+
+
+def load_driver(root: Path, name: str):
+    """The class ``Driver`` of ``root/bench/drivers/<name>.py``."""
+    return load_file(root, "drivers", name).Driver
