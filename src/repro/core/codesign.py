@@ -262,6 +262,9 @@ class CodesignConfig:
         are enabled: genome bytes from different axis sets must never
         alias, but every memo/checkpoint persisted before the axes
         existed (all ADC-only by construction) must keep validating.
+        Likewise ``matmul_precision``, JAX's default matmul precision when
+        the run sets one other than "default": the QAT dots, and so the
+        accuracies, differ from one precision to another on the TPU.
         """
         fp = {
             "dataset": self.dataset,
@@ -273,6 +276,9 @@ class CodesignConfig:
         axes = self.axes()
         if axes != ("adc",):
             fp["genome_axes"] = list(axes)
+        precision = jax.config.jax_default_matmul_precision
+        if precision not in (None, "default"):
+            fp["matmul_precision"] = str(precision)
         return fp
 
     def search_fingerprint(self) -> dict:

@@ -102,10 +102,11 @@ def quantize_layer_weights(w: jnp.ndarray, bits: jnp.ndarray | float) -> jnp.nda
     populations stay ONE jitted program and the selected branch's values
     are bit-identical to calling that quantizer alone.
     """
-    bits = jnp.asarray(bits, jnp.float32)
-    po2 = quantize_pow2(w, jnp.maximum(bits, 1.0))
-    tern = quantize_ternary(w)
-    return jnp.where(bits > 0.0, po2, tern)
+    with spans.scope("wprec"):
+        bits = jnp.asarray(bits, jnp.float32)
+        po2 = quantize_pow2(w, jnp.maximum(bits, 1.0))
+        tern = quantize_ternary(w)
+        return jnp.where(bits > 0.0, po2, tern)
 
 
 # --- printed activation approximations (arXiv 2312.17612) ---------------
@@ -147,8 +148,9 @@ def act_approx(h: jnp.ndarray, sel: jnp.ndarray | int) -> jnp.ndarray:
     ``lax.switch`` lowers to computing every branch + select, so values of
     the selected branch match calling it directly, bit for bit.
     """
-    sel = jnp.asarray(sel, jnp.int32)
-    return jax.lax.switch(sel, ACT_APPROX_FNS, h)
+    with spans.scope("act"):
+        sel = jnp.asarray(sel, jnp.int32)
+        return jax.lax.switch(sel, ACT_APPROX_FNS, h)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -64,8 +64,9 @@ COUNTERS = (
     "trainer.scanned_row_steps",  # rows x max_steps x max_batch the scan computes
 )
 
-# jax.named_scope names inside the QAT program (op_name metadata)
-SCOPES = ("adc", "layer", "gather", "loss", "sgd", "test")
+# jax.named_scope names inside the QAT program (op_name metadata); "act"
+# and "wprec" only in programs whose genome has those axes
+SCOPES = ("adc", "layer", "gather", "loss", "sgd", "test", "act", "wprec")
 
 _SPAN_SET = frozenset(SPANS)
 _SCOPE_SET = frozenset(SCOPES)

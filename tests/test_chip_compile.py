@@ -11,6 +11,8 @@ only one process at a time may load the TPU library, and every test worker
 imports every test file.
 """
 
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -57,12 +59,18 @@ def cardio():
     return (Xtr, ytr, Xte, yte), mlp
 
 
-def _row_shapes(lead: tuple[int, ...], sharding_for):
-    """ShapeDtypeStructs of one evaluator call (ADC-only genome)."""
+def _row_shapes(lead: tuple[int, ...], sharding_for, axes=("adc",)):
+    """ShapeDtypeStructs of one evaluator call (ADC-only genome, or with
+    the activation selector and per-layer weight bits of the "act" and
+    "wprec" axes)."""
     specs = [
         ((C, 1 << N_BITS), jnp.bool_), ((), jnp.float32), ((), jnp.float32),
         ((), jnp.int32), ((), jnp.int32), ((), jnp.float32), ((), jnp.int32),
     ]
+    if "act" in axes:
+        specs.append(((1,), jnp.int32))
+    if "wprec" in axes:
+        specs.append(((2,), jnp.float32))
     out = []
     for shape, dt in specs:
         full = lead + shape
@@ -131,3 +139,19 @@ def test_stacked_island_program_compiles_for_four_chips(topo, cardio):
     out = compiled.output_shardings
     assert len(out.device_set) == 4 and out.spec[0] == "island"
     assert np.prod(compiled.input_shardings[0][0].mesh.devices.shape) == 4
+
+
+def test_three_axis_population_program_compiles_for_v5e_at_highest(one_chip, cardio):
+    """The three-axis genome's paper-budget program with float32 dots: every
+    dot at highest, and operations under the ``act`` and ``wprec`` scopes."""
+    data, mlp = cardio
+    axes = ("adc", "act", "wprec")
+    ev = trainer.make_population_evaluator(
+        *data, mlp, trainer.EvalConfig(max_steps=600, genome_axes=axes))
+    with jax.default_matmul_precision("highest"):
+        text = ev.program.lower(*_row_shapes((POP,), lambda _: one_chip, axes)).compile().as_text()
+    precisions = set(re.findall(r"operand_precision=\{([^}]*)\}", text))
+    assert precisions == {"highest,highest"}
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("act", "wprec"):
+        assert any(re.search(rf"(^|[/(]){scope}\)*/", o) for o in op_names), scope

@@ -15,6 +15,7 @@ Three layers of guarantees for the multi-axis search space:
 """
 
 import itertools
+import json
 import zlib
 
 import jax
@@ -434,3 +435,26 @@ def test_memo_fingerprint_only_widens_when_axes_do():
     assert "genome_axes" not in adc.memo_fingerprint()
     assert full.memo_fingerprint()["genome_axes"] == ["adc", "act", "wprec"]
     assert "genome_axes" not in adc.search_fingerprint()
+
+
+@pytest.mark.ci
+def test_fingerprints_record_a_matmul_precision_other_than_default():
+    cfgs = [codesign.CodesignConfig(dataset="seeds"),
+            codesign.CodesignConfig(dataset="cardio", genome_axes=("adc", "act", "wprec"))]
+    plain = {"dataset": "seeds", "adc_bits": 4, "step_scale": 1.0, "max_steps": 600, "seed": 0}
+    # at JAX's default precision every configuration's fingerprints are
+    # what they were before the key existed, to the byte
+    assert json.dumps(cfgs[0].memo_fingerprint()) == json.dumps(plain)
+    before = [(json.dumps(c.memo_fingerprint()), json.dumps(c.search_fingerprint()))
+              for c in cfgs]
+    for prec in ("default", "highest", "bfloat16"):
+        with jax.default_matmul_precision(prec):
+            for c, (memo, search) in zip(cfgs, before):
+                fp, sfp = c.memo_fingerprint(), c.search_fingerprint()
+                if prec == "default":
+                    assert (json.dumps(fp), json.dumps(sfp)) == (memo, search)
+                else:
+                    # a memo kept at one precision is never read at another
+                    assert fp.pop("matmul_precision") == prec
+                    assert sfp.pop("matmul_precision") == prec
+                    assert (json.dumps(fp), json.dumps(sfp)) == (memo, search)

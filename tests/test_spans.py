@@ -137,21 +137,32 @@ def test_history_times_are_the_generation_and_evaluate_spans(driver):
     assert _names(log).count("nsga2.plan") == 1 + len(gens)
 
 
-def _seeds_evaluator(max_steps=6, step_scale=0.02):
+AXES = ("adc", "act", "wprec")
+AXES_SCOPES = ("act", "wprec")  # only in programs whose genome has those axes
+
+
+def _seeds_evaluator(max_steps=6, step_scale=0.02, axes=("adc",)):
     X, y, spec = uci_synth.load("seeds")
     data = uci_synth.stratified_split(X, y, 0.7, 0)
     mlp = qat.MLPConfig((spec.n_features, spec.hidden, spec.n_classes), adc_bits=4)
-    cfg = trainer.EvalConfig(max_steps=max_steps, step_scale=step_scale)
+    cfg = trainer.EvalConfig(max_steps=max_steps, step_scale=step_scale, genome_axes=axes)
     return trainer.make_population_evaluator(*data, mlp, cfg), spec, data, cfg
 
 
-def _rows(spec, n, seed):
+def _rows(spec, n, seed, axes=("adc",)):
     rng = np.random.default_rng(seed)
     masks = rng.uniform(size=(n, chromosome.n_mask_bits(spec.n_features, 4))) < 0.6
-    cats = np.stack([rng.integers(0, c, n) for c in chromosome.cat_cardinalities(("adc",), 2)], 1)
-    dec = chromosome.decode_batch(masks, cats, spec.n_features, 4)
+    cats = np.stack([rng.integers(0, c, n) for c in chromosome.cat_cardinalities(axes, 2)], 1)
+    dec = chromosome.decode_batch(masks, cats, spec.n_features, 4, axes=axes)
     return (dec["masks"], dec["weight_bits"], dec["act_bits"], dec["batch_size"],
-            dec["epochs"], dec["lr"], np.arange(n, dtype=np.int32))
+            dec["epochs"], dec["lr"], np.arange(n, dtype=np.int32),
+            *codesign._extra_rows(dec))
+
+
+def _program_text(axes=("adc",)):
+    ev, spec, _, _ = _seeds_evaluator(axes=axes)
+    rows = _rows(spec, 4, 0, axes)
+    return ev.program.lower(*(ev.shard_fn(a) for a in rows)).compile().as_text()
 
 
 def test_program_builds_tick_once_per_new_bucket():
@@ -202,14 +213,42 @@ def test_island_evaluator_counts_its_stacked_rows():
     assert c["trainer.scanned_row_steps"] == 2 * 4 * 6 * 128
 
 
+def _in_scope(name, op_names):
+    return any(re.search(rf"(^|[/(]){name}\)*/", o) for o in op_names)
+
+
 def test_scopes_are_in_the_program_metadata():
-    ev, spec, _, _ = _seeds_evaluator()
-    rows = _rows(spec, 4, 0)
-    text = ev.program.lower(*(ev.shard_fn(a) for a in rows)).compile().as_text()
-    op_names = re.findall(r'op_name="([^"]*)"', text)
+    op_names = re.findall(r'op_name="([^"]*)"', _program_text())
     for name in spans.SCOPES:
-        assert any(re.search(rf"(^|[/(]){name}\)*/", o) for o in op_names), name
+        assert _in_scope(name, op_names) == (name not in AXES_SCOPES), name
     assert any("transpose(jvp(layer))" in o for o in op_names)  # backward ops keep the scope
+    # the three-axis genome's program carries every scope, its own two too
+    op_names = re.findall(r'op_name="([^"]*)"', _program_text(AXES))
+    for name in spans.SCOPES:
+        assert _in_scope(name, op_names), name
+
+
+def _without_source_lines(text):
+    """A compiled program's text without where in the source each
+    operation was traced (the tables of files, functions and frames, and
+    each operation's reference to them): operations, shapes, op_names."""
+    text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n(\d+ [^\n]*\n)*",
+                  "\n", text)
+    return re.sub(r" ?(stack_frame_id|source_(end_)?(line|column))=\d+| ?source_file=\"[^\"]*\"",
+                  "", text)
+
+
+def test_three_axis_scopes_leave_the_adc_only_program_alone(monkeypatch):
+    texts = {axes: _without_source_lines(_program_text(axes)) for axes in (("adc",), AXES)}
+    scope = spans.scope
+    monkeypatch.setattr(
+        spans, "scope",
+        lambda name: contextlib.nullcontext() if name in AXES_SCOPES else scope(name))
+    # without the two scopes the ADC-only program's text is the same, to
+    # the byte; the three-axis program's is not (the two scopes are in it)
+    assert _without_source_lines(_program_text()) == texts[("adc",)]
+    assert "op_name=" in texts[("adc",)]
+    assert _without_source_lines(_program_text(AXES)) != texts[AXES]
 
 
 def test_search_is_bit_for_bit_with_scopes_in(monkeypatch):
