@@ -230,9 +230,11 @@ def phase_reference(cfg: codesign.CodesignConfig, main_memo) -> None:
     ev = trainer.make_population_evaluator(*split, mlp, _eval_cfg(cfg, False))
     acc_batch = np.asarray(ev(*rows))
     with jax.default_matmul_precision("highest"):
-        train_one = jax.jit(trainer._make_train_one(*split, mlp, _eval_cfg(cfg, False)))
+        ecfg = _eval_cfg(cfg, False)
+        train_one = jax.jit(trainer._make_train_one(mlp, ecfg))
+        data = trainer._split_args(*split, ecfg.seed)
         ref = np.asarray([
-            train_one(*(np.asarray(a)[i] for a in rows)) for i in range(N_ROWS)
+            train_one(*(np.asarray(a)[i] for a in rows), data) for i in range(N_ROWS)
         ])
     diff = np.abs((1.0 - ref) - miss)
     n_test = split[3].shape[0]

@@ -60,6 +60,7 @@ COUNTERS = (
     "trainer.rows",               # real rows sent to an evaluator
     "trainer.padded_rows",        # bucket padding rows computed and dropped
     "trainer.program_builds",     # traces of an evaluator's jitted program
+    "trainer.program_reuses",     # evaluator builds that found their program built
     "trainer.useful_row_steps",   # sum over real rows of steps x batch trained
     "trainer.scanned_row_steps",  # rows x max_steps x max_batch the scan computes
 )

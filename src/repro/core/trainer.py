@@ -23,6 +23,17 @@ of the device count) so the memoized NSGA-II engine — which submits a
 *varying* number of unseen genomes per generation — re-uses a handful of
 compiled programs instead of recompiling per population size.
 
+The programs are shared across evaluators.  The data split and the
+trainer's base key ``PRNGKey(cfg.seed)`` are arguments of the row program,
+not constants of it, so one jitted program serves every evaluator of the
+same table shapes, MLP, ``EvalConfig`` (its seed aside), mesh and island
+count; a module-level cache of bounded size hands it out.  Each evaluator
+places its split on its mesh once, replicated, at its first call, and
+passes it with the rows on every call.  A search that builds fresh
+evaluators therefore traces and lowers nothing a previous search (or a
+warm-up) already did: JAX traces a shared program once per bucket and per
+matmul precision, which its own trace cache keys on.
+
 :func:`make_island_evaluator` is the island-model variant: the K islands'
 per-generation unseen batches are padded to one common bucket, stacked
 into ``(K, B, …)`` tensors and evaluated as ONE ``vmap(vmap(train_one))``
@@ -48,16 +59,20 @@ padding, same sharding, same compiled program, same values.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import chromosome, qat, spans
 from repro.parallel import sharding as shd
 
-__all__ = ["EvalConfig", "make_population_evaluator", "make_island_evaluator"]
+__all__ = ["EvalConfig", "make_population_evaluator", "make_island_evaluator",
+           "clear_programs"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,22 +95,27 @@ class EvalConfig:
     genome_axes: tuple[str, ...] = ("adc",)
 
 
-def _make_train_one(
-    X_tr: np.ndarray,
-    y_tr: np.ndarray,
-    X_te: np.ndarray,
-    y_te: np.ndarray,
-    mlp_cfg: qat.MLPConfig,
-    cfg: EvalConfig,
-):
+def _split_args(X_tr, y_tr, X_te, y_te, seed: int) -> tuple:
+    """The split a row program takes after its rows: the training and test
+    tables, typed as the program reads them, and ``PRNGKey(seed)``, the
+    trainer's base key that each row folds its own seed into."""
+    return (jnp.asarray(X_tr, jnp.float32), jnp.asarray(y_tr, jnp.int32),
+            jnp.asarray(X_te, jnp.float32), jnp.asarray(y_te, jnp.int32),
+            jax.random.PRNGKey(seed))
+
+
+def _make_train_one(mlp_cfg: qat.MLPConfig, cfg: EvalConfig):
     """The per-chromosome QAT training program shared by both evaluators.
 
-    Returns ``train_one(mask, wb, ab, bs, ep, lr, seed, *extra) -> test_acc``
-    — a pure function of the chromosome row only (the training seed arrives
-    as an input, derived upstream from the genome bytes), which is what
-    makes its result independent of which batch, bucket, or island stack the
-    row is evaluated in: the population and island evaluators vmap the SAME
-    row program, so their per-row outputs agree bit-for-bit.
+    Returns ``train_one(mask, wb, ab, bs, ep, lr, seed, *extra, split) ->
+    test_acc``, ``split`` being ``(X_tr, y_tr, X_te, y_te, base_key)`` of
+    :func:`_split_args` — a pure function of the chromosome row and the
+    split (the training seed arrives as an input, derived upstream from the
+    genome bytes, and is folded into ``base_key``), which is what makes its
+    result independent of which batch, bucket, or island stack the row is
+    evaluated in: the population and island evaluators vmap the SAME row
+    program over the rows, the split unmapped, so their per-row outputs
+    agree bit-for-bit.
 
     ``extra`` carries the generalized-genome rows for the axes enabled in
     ``cfg.genome_axes``, in canonical axis order: the "act" selector vector,
@@ -103,17 +123,13 @@ def _make_train_one(
     ``("adc",)`` no extras exist and the traced program is exactly the
     pre-axes one.
     """
-    X_tr = jnp.asarray(X_tr, jnp.float32)
-    y_tr = jnp.asarray(y_tr, jnp.int32)
-    X_te = jnp.asarray(X_te, jnp.float32)
-    y_te = jnp.asarray(y_te, jnp.int32)
-    n_train = X_tr.shape[0]
     axes = chromosome.normalize_axes(cfg.genome_axes)
     has_act = "act" in axes
     has_wprec = "wprec" in axes
     n_extra = int(has_act) + int(has_wprec)
 
-    def train_one(mask, wb, ab, bs, ep, lr, seed, *extra):
+    def train_one(mask, wb, ab, bs, ep, lr, seed, *rest):
+        *extra, (X_tr, y_tr, X_te, y_te, base_key) = rest
         if len(extra) != n_extra:
             raise TypeError(
                 f"genome axes {axes} expect {n_extra} extra row arrays, "
@@ -122,7 +138,8 @@ def _make_train_one(
         it = iter(extra)
         act_sel = next(it) if has_act else None
         layer_wb = next(it) if has_wprec else None
-        key = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), seed)
+        n_train = X_tr.shape[0]
+        key = jax.random.fold_in(base_key, seed)
         params = qat.init_mlp(key, mlp_cfg)
         velocity = jax.tree.map(jnp.zeros_like, params)
 
@@ -167,6 +184,93 @@ def _make_train_one(
             return qat.accuracy(logits, y_te)
 
     return train_one
+
+
+# The evaluators' jitted programs, one per row-program factory, table
+# shapes and dtypes, MLP, EvalConfig (its seed aside, an argument), mesh
+# and island count; the least recently used goes past _PROGRAMS_MAX.  The
+# factory is part of the key so that a replaced ``_make_train_one`` builds
+# programs of its own.
+_PROGRAMS_MAX = 32
+_programs: collections.OrderedDict = collections.OrderedDict()
+_programs_lock = threading.Lock()
+
+
+def _build_program(mlp_cfg: qat.MLPConfig, cfg: EvalConfig, stacked: bool):
+    """``jit(vmap(train_one))``, or ``vmap(vmap(...))`` for ``(K, B)``
+    island stacks: the rows mapped, the split (the last argument) not."""
+    train_one = _make_train_one(mlp_cfg, cfg)
+
+    def in_axes(n_args):
+        return (0,) * (n_args - 1) + (None,)
+
+    if stacked:
+        def _evaluate_stacked(*args):
+            spans.count("trainer.program_builds", 1)  # the body runs once per trace
+            axes = in_axes(len(args))
+            return jax.vmap(jax.vmap(train_one, in_axes=axes), in_axes=axes)(*args)
+
+        return jax.jit(_evaluate_stacked)
+
+    def _evaluate_padded(*args):
+        spans.count("trainer.program_builds", 1)  # the body runs once per trace
+        return jax.vmap(train_one, in_axes=in_axes(len(args)))(*args)
+
+    return jax.jit(_evaluate_padded)
+
+
+def _shared_program(split, mlp_cfg, cfg: EvalConfig, mesh, num_islands: int | None = None):
+    """The one program of every evaluator with this key, built on a miss;
+    ``num_islands`` None for the population program."""
+    key = (_make_train_one, tuple((a.shape, a.dtype) for a in split), mlp_cfg,
+           dataclasses.replace(cfg, seed=0), mesh, num_islands)
+    with _programs_lock:
+        program = _programs.get(key)
+        if program is not None:
+            _programs.move_to_end(key)
+            spans.count("trainer.program_reuses", 1)
+            return program
+        program = _programs[key] = _build_program(mlp_cfg, cfg, num_islands is not None)
+        while len(_programs) > _PROGRAMS_MAX:
+            _programs.popitem(last=False)
+        return program
+
+
+def clear_programs() -> None:
+    """Forget every shared program: the next evaluator builds and traces
+    its own.  For tests that change code a program is traced from."""
+    with _programs_lock:
+        _programs.clear()
+
+
+class _BoundProgram:
+    """The shared program of an evaluator's key with the evaluator's split
+    bound in.  Called with rows placed on ``mesh``, it runs the program on
+    them and the split, which it places on the mesh, replicated, at the
+    first call; ``lower(*rows)`` lowers it for rows placed as ``rows`` are
+    (arrays or ``jax.ShapeDtypeStruct``\\ s), the split replicated over
+    their devices."""
+
+    def __init__(self, split, mlp_cfg, cfg: EvalConfig, mesh, num_islands: int | None = None):
+        self.program = _shared_program(split, mlp_cfg, cfg, mesh, num_islands)
+        self.split, self.mesh = split, mesh
+        self._placed = None
+
+    def __call__(self, *rows):
+        if self._placed is None:
+            # at the first call, not at build: an evaluator on a mesh of
+            # devices this process cannot address (a topology described to
+            # compile for) still lowers its program
+            replicated = NamedSharding(self.mesh, PartitionSpec())
+            self._placed = tuple(jax.device_put(a, replicated) for a in self.split)
+        return self.program(*rows, self._placed)
+
+    def lower(self, *rows):
+        sharding = getattr(rows[0], "sharding", None)
+        if isinstance(sharding, NamedSharding):
+            sharding = NamedSharding(sharding.mesh, PartitionSpec())
+        return self.program.lower(*rows, tuple(
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding) for a in self.split))
 
 
 def _useful_row_steps(bs, ep, n_train: int, cfg: EvalConfig) -> int:
@@ -226,7 +330,6 @@ def make_population_evaluator(
     re-lowers the same row program onto a fresh mesh with everything else
     unchanged.
     """
-    train_one = _make_train_one(X_tr, y_tr, X_te, y_te, mlp_cfg, cfg)
     n_train = np.shape(X_tr)[0]
 
     pop_mesh = shd.population_mesh(n_devices) if mesh is None else mesh
@@ -236,11 +339,8 @@ def make_population_evaluator(
     # full replication (every device training the whole population)
     n_dev = max(int(np.prod(list(pop_mesh.shape.values()))), 1)
     granule = -(-max(cfg.pad_granule, 1) // n_dev) * n_dev
-
-    @jax.jit
-    def _evaluate_padded(*args):
-        spans.count("trainer.program_builds", 1)  # the body runs once per trace
-        return jax.vmap(train_one)(*args)
+    program = _BoundProgram(_split_args(X_tr, y_tr, X_te, y_te, cfg.seed), mlp_cfg, cfg,
+                            pop_mesh)
 
     def _shard(arr):
         """Commit one population-stacked array to its sharded layout."""
@@ -265,7 +365,7 @@ def make_population_evaluator(
                 # Auto mesh: no host round-trip, and one program for any caller
                 placed = [_shard(a) for a in args]
             with spans.span("trainer.dispatch"):
-                acc = _evaluate_padded(*placed)
+                acc = program(*placed)
             return acc if bucket == P else acc[:P]
 
     def dispatch(*args):
@@ -295,7 +395,7 @@ def make_population_evaluator(
     evaluate.dispatch = dispatch
     evaluate.mesh = pop_mesh
     evaluate.shard_fn = _shard        # introspection hooks: the placement
-    evaluate.program = _evaluate_padded  # and the jitted program to lower
+    evaluate.program = program        # and the program to lower
     evaluate.rebuild = rebuild
     return evaluate
 
@@ -336,7 +436,6 @@ def make_island_evaluator(
     """
     if num_islands < 1:
         raise ValueError(f"num_islands must be >= 1, got {num_islands}")
-    train_one = _make_train_one(X_tr, y_tr, X_te, y_te, mlp_cfg, cfg)
     n_train = np.shape(X_tr)[0]
 
     isl_mesh = shd.island_mesh(num_islands, n_devices) if mesh is None else mesh
@@ -345,11 +444,8 @@ def make_island_evaluator(
     # bucket granule must divide the group size, not the whole device count
     group = max(int(dict(isl_mesh.shape).get("data", 1)), 1)
     granule = -(-max(cfg.pad_granule, 1) // group) * group
-
-    @jax.jit
-    def _evaluate_stacked(*args):
-        spans.count("trainer.program_builds", 1)  # the body runs once per trace
-        return jax.vmap(jax.vmap(train_one))(*args)
+    program = _BoundProgram(_split_args(X_tr, y_tr, X_te, y_te, cfg.seed), mlp_cfg, cfg,
+                            isl_mesh, num_islands)
 
     def _shard(arr):
         """Commit one (K, B, ...) island-stacked array to its layout."""
@@ -378,7 +474,7 @@ def make_island_evaluator(
             with spans.span("trainer.input"):
                 stacked = _stack(batches, sizes, bucket)
             with spans.span("trainer.dispatch"):
-                return _evaluate_stacked(*stacked), sizes
+                return program(*stacked), sizes
 
     def _stack(batches, sizes, bucket):
         """Pad every island's rows to ``bucket``, stack and place them."""
@@ -444,7 +540,7 @@ def make_island_evaluator(
     evaluate.mesh = isl_mesh          # introspection hooks for tests and
     evaluate.granule = granule        # benchmarks: the device-group layout
     evaluate.shard_fn = _shard        # the stacked tensors are placed with
-    evaluate.program = _evaluate_stacked
+    evaluate.program = program
     evaluate.dispatch = dispatch
     evaluate.rebuild = rebuild
     return evaluate

@@ -145,6 +145,7 @@ def test_no_gather_or_sort_in_the_adc_stage(monkeypatch):
 
     assert _adc_sorts_and_gathers(program_text()) == []
     monkeypatch.setattr(adc, "quantize_pruned", _gather_quantize_pruned)
+    trainer.clear_programs()  # the evaluators' programs are shared
     assert _adc_sorts_and_gathers(program_text())
 
 
@@ -154,6 +155,7 @@ def test_population_accuracies_are_bit_identical_to_the_gather_oracle(monkeypatc
     assert not rows[0].all()  # pruned levels take part
     got = np.asarray(ev(*rows))
     monkeypatch.setattr(adc, "quantize_pruned", _gather_quantize_pruned)
+    trainer.clear_programs()  # the evaluators' programs are shared
     oracle_ev, _ = _seeds_evaluator()
     oracle = np.asarray(oracle_ev(*rows))
     np.testing.assert_array_equal(got, oracle)

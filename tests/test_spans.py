@@ -166,6 +166,7 @@ def _program_text(axes=("adc",)):
 
 
 def test_program_builds_tick_once_per_new_bucket():
+    trainer.clear_programs()  # the evaluator's program is built here, not found
     ev, spec, _, _ = _seeds_evaluator()
     with spans.recording() as log:
         ev(*_rows(spec, 3, 0))  # bucket 4: built
@@ -244,6 +245,7 @@ def test_three_axis_scopes_leave_the_adc_only_program_alone(monkeypatch):
     monkeypatch.setattr(
         spans, "scope",
         lambda name: contextlib.nullcontext() if name in AXES_SCOPES else scope(name))
+    trainer.clear_programs()  # programs traced with the scopes in are shared
     # without the two scopes the ADC-only program's text is the same, to
     # the byte; the three-axis program's is not (the two scopes are in it)
     assert _without_source_lines(_program_text()) == texts[("adc",)]
@@ -257,6 +259,7 @@ def test_search_is_bit_for_bit_with_scopes_in(monkeypatch):
     with spans.recording() as log:
         scoped = codesign.run_codesign(cfg)
     monkeypatch.setattr(spans, "scope", lambda name: contextlib.nullcontext())
+    trainer.clear_programs()  # else the plain search reuses the scoped programs
     plain = codesign.run_codesign(cfg)
     np.testing.assert_array_equal(scoped.front_acc, plain.front_acc)
     np.testing.assert_array_equal(scoped.front_masks, plain.front_masks)
@@ -269,6 +272,6 @@ def test_search_is_bit_for_bit_with_scopes_in(monkeypatch):
     last_call = max(i for i, n in enumerate(names) if n == "trainer.call")
     assert names[recs[last_call]["parent"]] == "codesign.baseline"
     assert set(names) == set(spans.SPANS)
-    # a search builds one program per bucket it calls its fresh evaluator with
+    # a search that starts from no program builds one per bucket it calls
     buckets = {s["attrs"]["bucket"] for s in recs if s["name"] == "trainer.call"}
     assert log.counters["trainer.program_builds"] == len(buckets)
