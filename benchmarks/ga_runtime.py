@@ -428,9 +428,11 @@ def run_pipelined(
     async driver computes exactly what the synchronous one does — same
     RNG order, same memo insertion order, so ``*_matches_sync`` asserts
     identical rows trained and identical front hypervolume — it only
-    moves *when the host blocks*: batches are dispatched as non-blocking
-    device programs and the host runs the next island's variation and
-    memo planning (islands) or the area pass (single) while they train.
+    moves *when the host blocks*: island batches are dispatched as
+    non-blocking device programs and the host plans the next island's
+    pool while they train.  On one population the flag changes nothing
+    (the blocking callback already overlaps the area pass), so the
+    single pair times one code path twice.
 
     Reported per engine: per-generation wall-clock median (``gen_s``) and
     the blocked-time median (``eval_s`` — for the async island driver

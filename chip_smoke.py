@@ -53,8 +53,7 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import printed_mlp  # noqa: E402
-from repro.core import chromosome, codesign, memo_store, nsga2, qat, trainer  # noqa: E402
-from repro.data import uci_synth  # noqa: E402
+from repro.core import codesign, memo_store, nsga2, trainer  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 DATASET = "cardio"
@@ -145,28 +144,18 @@ def phase_main(cfg: codesign.CodesignConfig):
     return res, memo
 
 
-def _data(cfg: codesign.CodesignConfig):
-    X, y, spec = uci_synth.load(cfg.dataset)
-    split = uci_synth.stratified_split(X, y, 0.7, cfg.seed)
-    mlp = qat.MLPConfig((spec.n_features, spec.hidden, spec.n_classes),
-                        adc_bits=cfg.adc_bits)
-    return split, mlp
+def _evaluator(prob, fused: bool = False):
+    """A population evaluator as ``run_codesign`` builds it for ``prob``."""
+    ecfg = dataclasses.replace(prob.eval_cfg, use_fused_kernel=fused)
+    return trainer.make_population_evaluator(*prob.split, prob.mlp_cfg, ecfg)
 
 
-def _eval_cfg(cfg: codesign.CodesignConfig, fused: bool) -> trainer.EvalConfig:
-    """The EvalConfig ``run_codesign`` builds for ``cfg``."""
-    return trainer.EvalConfig(
-        max_steps=cfg.max_steps, step_scale=cfg.step_scale, seed=cfg.seed,
-        use_fused_kernel=fused, genome_axes=cfg.axes(),
-    )
-
-
-def front_rows(cfg: codesign.CodesignConfig, memo: dict, n: int):
+def front_rows(prob, memo: dict, n: int):
     """Evaluator rows of ``n`` genomes of the memo's first front.
 
-    Returns ``(rows, miss, n_front)``: the seven ADC-only row arrays
-    (cycled when the front has fewer than ``n`` members), each row's
-    accuracy miss as the search recorded it, and the front's size.  The
+    Returns ``(rows, miss, n_front)``: the evaluator rows ``prob.rows``
+    builds (cycled when the front has fewer than ``n`` members), each
+    row's accuracy miss as the search recorded it, and the front's size.  The
     miss is ``1 - acc`` as ``run_codesign`` computes it from the
     evaluator's float32 accuracy, so ``1.0 - acc`` of a re-scored float32
     row compares to it exactly.
@@ -175,14 +164,10 @@ def front_rows(cfg: codesign.CodesignConfig, memo: dict, n: int):
     objs = np.stack([memo[k] for k in keys])
     front = nsga2.fast_non_dominated_sort(objs)[0]
     pick = np.resize(front, n)
-    spec = uci_synth.DATASETS[cfg.dataset]
-    n_mask = chromosome.n_mask_bits(spec.n_features, cfg.adc_bits)
+    n_mask = prob.n_mask_bits
     masks = np.stack([np.frombuffer(keys[i][:n_mask], np.uint8) for i in pick])
     cats = np.stack([np.frombuffer(keys[i][n_mask:], np.int64) for i in pick])
-    masks = masks.astype(bool)
-    dec = chromosome.decode_batch(masks, cats, spec.n_features, cfg.adc_bits)
-    rows = (dec["masks"], dec["weight_bits"], dec["act_bits"], dec["batch_size"],
-            dec["epochs"], dec["lr"], codesign._genome_seeds(masks, cats))
+    _, rows = prob.rows(masks.astype(bool), cats)
     return rows, objs[pick, 0], len(front)
 
 
@@ -196,12 +181,9 @@ def phase_fused(cfg: codesign.CodesignConfig, main_res, main_memo) -> None:
     t0 = time.perf_counter()
     res, _ = run_search(dataclasses.replace(cfg, use_fused_kernel=True))
     _check_result(res)
-    split, mlp = _data(cfg)
-    rows, _, n_front = front_rows(cfg, main_memo, N_ROWS)
-    ev_xla, ev_fused = (
-        trainer.make_population_evaluator(*split, mlp, _eval_cfg(cfg, fused))
-        for fused in (False, True)
-    )
+    prob = codesign._problem(cfg)
+    rows, _, n_front = front_rows(prob, main_memo, N_ROWS)
+    ev_xla, ev_fused = (_evaluator(prob, fused) for fused in (False, True))
     assert_kernel_compiled(ev_fused, rows)
     acc_xla = np.asarray(ev_xla(*rows))
     acc_fused = np.asarray(ev_fused(*rows))
@@ -225,19 +207,17 @@ def phase_fused(cfg: codesign.CodesignConfig, main_res, main_memo) -> None:
 
 def phase_reference(cfg: codesign.CodesignConfig, main_memo) -> None:
     t0 = time.perf_counter()
-    split, mlp = _data(cfg)
-    rows, miss, _ = front_rows(cfg, main_memo, N_ROWS)
-    ev = trainer.make_population_evaluator(*split, mlp, _eval_cfg(cfg, False))
-    acc_batch = np.asarray(ev(*rows))
+    prob = codesign._problem(cfg)
+    rows, miss, _ = front_rows(prob, main_memo, N_ROWS)
+    acc_batch = np.asarray(_evaluator(prob)(*rows))
     with jax.default_matmul_precision("highest"):
-        ecfg = _eval_cfg(cfg, False)
-        train_one = jax.jit(trainer._make_train_one(mlp, ecfg))
-        data = trainer._split_args(*split, ecfg.seed)
+        train_one = jax.jit(trainer._make_train_one(prob.mlp_cfg, prob.eval_cfg))
+        data = trainer._split_args(*prob.split, prob.eval_cfg.seed)
         ref = np.asarray([
             train_one(*(np.asarray(a)[i] for a in rows), data) for i in range(N_ROWS)
         ])
     diff = np.abs((1.0 - ref) - miss)
-    n_test = split[3].shape[0]
+    n_test = prob.split[3].shape[0]
     _report(
         "reference", t0, rows=N_ROWS, n_test=n_test,
         max_abs_diff=float(diff.max()), mean_abs_diff=float(diff.mean()),
@@ -268,10 +248,10 @@ def phase_four_chips(cfg: codesign.CodesignConfig) -> None:
 
     # the stacked evaluator run_codesign builds: inputs and outputs must
     # span all four chips, not land on the first
-    split, mlp = _data(cfg)
-    ev = trainer.make_island_evaluator(*split, mlp, _eval_cfg(cfg, False),
+    prob = codesign._problem(cfg)
+    ev = trainer.make_island_evaluator(*prob.split, prob.mlp_cfg, prob.eval_cfg,
                                        num_islands=4)
-    rows, _, _ = front_rows(cfg, stk_memo, 4 * ev.granule)
+    rows, _, _ = front_rows(prob, stk_memo, 4 * ev.granule)
     stacked = [ev.shard_fn(np.reshape(a, (4, ev.granule) + a.shape[1:]))
                for a in rows]
     out = ev.program(*stacked)

@@ -61,9 +61,9 @@ def main():
     )
     ap.add_argument(
         "--async-pipeline", action="store_true",
-        help="dispatch QAT batches as non-blocking device programs and "
-             "overlap host-side variation/planning with the in-flight "
-             "evaluation (bit-for-bit identical results; see "
+        help="with --islands: dispatch each island's QAT batch as a "
+             "non-blocking device program and plan the next island's pool "
+             "while it trains (bit-for-bit identical results; see "
              "docs/PIPELINE.md for the timeline)",
     )
     ap.add_argument(

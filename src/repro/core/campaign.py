@@ -39,11 +39,11 @@ per-dataset ``CodesignResult`` then carries ``island_history`` and the
 ``migrations`` acceptance log, and the persisted memo is the merged
 cross-island table.
 
-``async_pipeline`` dispatches every QAT batch as a non-blocking device
-program and overlaps host-side NSGA-II variation/planning with the
-in-flight evaluation, blocking only at commit time — bit-for-bit the
-same search as the synchronous driver (``docs/PIPELINE.md`` walks the
-per-generation host/device timeline).
+``async_pipeline`` (with islands) dispatches each island's QAT batch as
+a non-blocking device program and overlaps the next island's memo
+planning with the in-flight evaluation, blocking only at commit time —
+bit-for-bit the same search as the sequential schedule
+(``docs/PIPELINE.md`` walks the per-generation host/device timeline).
 
     from repro.core import campaign
     res = campaign.run_campaign(campaign.CampaignConfig())

@@ -18,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import zlib
+from typing import Callable
 
 import jax
 import numpy as np
@@ -77,14 +78,13 @@ class CodesignConfig:
     # islands sequentially — bit-for-bit identical search results; requires
     # memoize.  Ignored when num_islands == 1.
     stacked_islands: bool = False
-    # async_pipeline=True overlaps host-side GA work with device-side QAT:
-    # each unseen batch is dispatched as a non-blocking device program
-    # (trainer's evaluate.dispatch) and the host blocks only at commit time
-    # — with num_islands > 1 the next island's variation/planning runs
+    # async_pipeline=True (num_islands > 1) dispatches each island's unseen
+    # batch as a non-blocking device program (trainer's evaluate.dispatch)
+    # and blocks only at commit time, so the next island's planning runs
     # while earlier islands train (requires memoize, mutually exclusive
-    # with stacked_islands); with num_islands == 1 the host-side area pass
-    # overlaps the in-flight accuracy program.  Bit-for-bit identical
-    # search results either way — only *when* the host blocks moves.
+    # with stacked_islands).  With num_islands == 1 it changes nothing:
+    # the blocking callback already runs the host-side area pass while the
+    # accuracy program trains.  Bit-for-bit identical search results.
     async_pipeline: bool = False
     # fault tolerance: with checkpoint_dir set, GA state (per-island
     # populations, RNG streams, histories, migration log) plus the shared
@@ -403,6 +403,101 @@ def _make_cost_batch(axes: tuple[str, ...], adc_bits: int, layer_sizes):
     return cost_batch, conv_area + mlp_area, conv_power + mlp_power
 
 
+@dataclasses.dataclass(frozen=True)
+class _Problem:
+    """What a search and the service backend share for one config: data
+    split, MLP and evaluator configs, genome shape, the evaluator-row
+    format and the (1 - acc, area ratio) objectives."""
+
+    cfg: CodesignConfig
+    spec: uci_synth.DatasetSpec
+    split: tuple  # (X_tr, y_tr, X_te, y_te)
+    mlp_cfg: qat.MLPConfig
+    eval_cfg: trainer.EvalConfig
+    axes: tuple[str, ...]
+    n_layers: int
+    cost_batch: Callable[[dict], tuple[np.ndarray, np.ndarray]]
+    norm_area: float
+    conv_area: float
+    conv_power: float
+
+    @property
+    def n_mask_bits(self) -> int:
+        return chromosome.n_mask_bits(self.spec.n_features, self.cfg.adc_bits)
+
+    @property
+    def cat_cards(self) -> tuple[int, ...]:
+        return tuple(chromosome.cat_cardinalities(self.axes, self.n_layers))
+
+    def decode(self, masks: np.ndarray, cats: np.ndarray) -> dict:
+        return chromosome.decode_batch(
+            masks, cats, self.spec.n_features, self.cfg.adc_bits,
+            axes=self.axes, n_layers=self.n_layers,
+        )
+
+    def rows(self, masks, cats, seeds=None) -> tuple[dict, tuple]:
+        """``(dec, rows)``: the decoded genomes and their evaluator rows
+        ``(masks, wb, ab, bs, ep, lr, seed, *extra)``; seeds default to
+        the genome-derived ones (:func:`_genome_seeds`)."""
+        dec = self.decode(masks, cats)
+        if seeds is None:
+            seeds = _genome_seeds(masks, cats)
+        return dec, (
+            dec["masks"], dec["weight_bits"], dec["act_bits"],
+            dec["batch_size"], dec["epochs"], dec["lr"], seeds,
+            *_extra_rows(dec),
+        )
+
+    def area(self, dec: dict) -> np.ndarray:
+        """The area objective: cost normalised to the conventional design."""
+        return self.cost_batch(dec)[0] / self.norm_area
+
+    @staticmethod
+    def objectives(accs, area: np.ndarray) -> np.ndarray:
+        return np.stack([1.0 - np.asarray(accs), area], axis=1)
+
+    def wave(self, batches, launch) -> list:
+        """Objectives of a stacked wave: every batch's rows go to
+        ``launch``, whose returned ``resolve()`` gives one accuracy array
+        per batch; the area pass runs in between.  Empty batches get
+        ``None``."""
+        decs, rows = zip(*(self.rows(m, c) for m, c in batches))
+        resolve = launch(list(rows))
+        areas = [self.area(d) for d in decs]
+        return [
+            self.objectives(a, ar) if len(ar) else None
+            for a, ar in zip(resolve(), areas)
+        ]
+
+
+def _problem(cfg: CodesignConfig) -> _Problem:
+    """Load and split the data and build the configs and cost function."""
+    X, y, spec = uci_synth.load(cfg.dataset)
+    mlp_cfg = qat.MLPConfig(
+        layer_sizes=(spec.n_features, spec.hidden, spec.n_classes),
+        adc_bits=cfg.adc_bits,
+    )
+    axes = cfg.axes()
+    conv_area, conv_power = area_model.conventional_cost(spec.n_features, cfg.adc_bits)
+    cost_batch, norm_area, _ = _make_cost_batch(axes, cfg.adc_bits, mlp_cfg.layer_sizes)
+    return _Problem(
+        cfg=cfg,
+        spec=spec,
+        split=tuple(uci_synth.stratified_split(X, y, 0.7, cfg.seed)),
+        mlp_cfg=mlp_cfg,
+        eval_cfg=trainer.EvalConfig(
+            max_steps=cfg.max_steps, step_scale=cfg.step_scale, seed=cfg.seed,
+            use_fused_kernel=cfg.use_fused_kernel, genome_axes=axes,
+        ),
+        axes=axes,
+        n_layers=len(mlp_cfg.layer_sizes) - 1,
+        cost_batch=cost_batch,
+        norm_area=norm_area,
+        conv_area=conv_area,
+        conv_power=conv_power,
+    )
+
+
 def run_codesign(cfg: CodesignConfig) -> CodesignResult:
     with spans.span("codesign.search", seed=cfg.seed, dataset=cfg.dataset):
         return _run_codesign(cfg)
@@ -411,34 +506,25 @@ def run_codesign(cfg: CodesignConfig) -> CodesignResult:
 def _run_codesign(cfg: CodesignConfig) -> CodesignResult:
     cfg.validate()
     with spans.span("codesign.setup"):
-        X, y, spec = uci_synth.load(cfg.dataset)
-        X_tr, y_tr, X_te, y_te = uci_synth.stratified_split(X, y, 0.7, cfg.seed)
-        mlp_cfg = qat.MLPConfig(
-            layer_sizes=(spec.n_features, spec.hidden, spec.n_classes),
-            adc_bits=cfg.adc_bits,
-        )
-        axes = cfg.axes()
-        n_layers = len(mlp_cfg.layer_sizes) - 1
-        eval_cfg = trainer.EvalConfig(
-            max_steps=cfg.max_steps, step_scale=cfg.step_scale, seed=cfg.seed,
-            use_fused_kernel=cfg.use_fused_kernel, genome_axes=axes,
-        )
+        prob = _problem(cfg)
         # evaluators live in a mutable dict so the elastic-recovery path can
         # swap in re-meshed replacements mid-campaign: every objective callback
         # below reads the dict at call time, not at closure-capture time
         evaluators: dict = {
             "pop": trainer.make_population_evaluator(
-                X_tr, y_tr, X_te, y_te, mlp_cfg, eval_cfg,
+                *prob.split, prob.mlp_cfg, prob.eval_cfg,
             )
         }
+        if cfg.num_islands > 1 and cfg.stacked_islands:
+            evaluators["islands"] = trainer.make_island_evaluator(
+                *prob.split, prob.mlp_cfg, prob.eval_cfg,
+                num_islands=cfg.num_islands,
+            )
 
         def rebuild_evaluators(n_devices: int | None = None) -> None:
             """Re-lower every evaluator onto the first ``n_devices`` devices."""
             for name in list(evaluators):
                 evaluators[name] = evaluators[name].rebuild(n_devices)
-
-        conv_area, conv_power = area_model.conventional_cost(spec.n_features, cfg.adc_bits)
-        cost_batch, norm_area, _ = _make_cost_batch(axes, cfg.adc_bits, mlp_cfg.layer_sizes)
 
     # chaos-drill tap: every batch actually sent to an evaluator passes
     # through here (one ordinal per non-empty batch, row count accumulated)
@@ -460,34 +546,23 @@ def _run_codesign(cfg: CodesignConfig) -> CodesignResult:
     def dispatch_evaluate(mask_genes: np.ndarray, cat_genes: np.ndarray):
         """Launch one batch's QAT program now; objectives on resolve().
 
-        The async-pipeline objective callback — and, resolved
-        immediately, the synchronous one (``evaluate`` below), so the
-        decode → seeds → train → area assembly exists exactly once.  The
-        accuracy program is only *dispatched* (``evaluate_acc.dispatch``);
-        the whole-population vectorized area pass then runs on the host
-        WHILE the devices train, and the returned closure blocks and
-        assembles the (1 − acc, area ratio) objectives at commit time.
+        Resolved at once it is the blocking callback (``evaluate``
+        below).  The accuracy program is only *dispatched*; the
+        vectorized area pass then runs on the host WHILE the device
+        trains, and the returned closure blocks and assembles the
+        objectives.
         """
         with spans.span("codesign.decode"):
-            dec = chromosome.decode_batch(
-                mask_genes, cat_genes, spec.n_features, cfg.adc_bits,
-                axes=axes, n_layers=n_layers,
-            )
-            seeds = _genome_seeds(mask_genes, cat_genes)
+            dec, rows = prob.rows(mask_genes, cat_genes)
         _observe_batch(mask_genes.shape[0])
-        resolve_acc = evaluators["pop"].dispatch(
-            dec["masks"], dec["weight_bits"], dec["act_bits"],
-            dec["batch_size"], dec["epochs"], dec["lr"], seeds,
-            *_extra_rows(dec),
-        )
-        # host-side objective tail, overlapped with the in-flight program
+        resolve_acc = evaluators["pop"].dispatch(*rows)
         with spans.span("codesign.area"):
-            areas, _ = cost_batch(dec)
+            area = prob.area(dec)
 
         def resolve() -> np.ndarray:
             with spans.span("codesign.wait"):
                 accs = np.asarray(resolve_acc())
-            return np.stack([1.0 - accs, areas / norm_area], axis=1)
+            return prob.objectives(accs, area)
 
         return resolve
 
@@ -495,46 +570,17 @@ def _run_codesign(cfg: CodesignConfig) -> CodesignResult:
         """Blocking objective callback: dispatch, then resolve at once."""
         return dispatch_evaluate(mask_genes, cat_genes)()
 
-    def make_stacked_evaluate():
-        """Cross-island objective callback for the stacked island driver.
-
-        One ``trainer.make_island_evaluator`` SPMD program trains every
-        island's unseen batch per generation; genome decode, per-genome
-        training seeds, and the vectorized area pass are identical to the
-        per-island ``evaluate`` above, so per-row objectives — and hence
-        the whole search — match the sequential driver bit for bit.
-        """
-        evaluators["islands"] = trainer.make_island_evaluator(
-            X_tr, y_tr, X_te, y_te, mlp_cfg, eval_cfg,
-            num_islands=cfg.num_islands,
-        )
-
-        def evaluate_stacked(batches):
-            decs = [
-                chromosome.decode_batch(
-                    m, c, spec.n_features, cfg.adc_bits,
-                    axes=axes, n_layers=n_layers,
-                )
-                for m, c in batches
-            ]
+    def evaluate_stacked(batches):
+        """One ``trainer.make_island_evaluator`` program trains every
+        island's unseen batch of a generation (called, not dispatched)."""
+        def launch(rows):
             for m, _ in batches:
                 if m.shape[0]:
                     _observe_batch(m.shape[0])
-            accs = evaluators["islands"]([
-                (d["masks"], d["weight_bits"], d["act_bits"],
-                 d["batch_size"], d["epochs"], d["lr"], _genome_seeds(m, c))
-                + _extra_rows(d)
-                for d, (m, c) in zip(decs, batches)
-            ])
-            out = []
-            for d, a in zip(decs, accs):
-                areas, _ = cost_batch(d)
-                out.append(
-                    np.stack([1.0 - np.asarray(a), areas / norm_area], axis=1)
-                )
-            return out
+            accs = evaluators["islands"](rows)
+            return lambda: accs
 
-        return evaluate_stacked
+        return prob.wave(batches, launch)
 
     preload = None
     if cfg.memo_path and cfg.memoize and memo_store.memo_path_exists(cfg.memo_path):
@@ -544,37 +590,26 @@ def _run_codesign(cfg: CodesignConfig) -> CodesignResult:
         memoize=cfg.memoize, crossover_rate=cfg.crossover_rate,
         mutation_rate=cfg.mutation_rate,
     )
-    n_mask_bits = chromosome.n_mask_bits(spec.n_features, cfg.adc_bits)
-    cat_cards = chromosome.cat_cardinalities(axes, n_layers)
     ga_kwargs = dict(
-        n_mask_bits=n_mask_bits,
-        cat_cardinalities=cat_cards,
+        n_mask_bits=prob.n_mask_bits,
+        cat_cardinalities=prob.cat_cards,
         evaluate=evaluate,
         cfg=ga_cfg,
         memo=preload,
-        screen=cfg.make_screen(n_mask_bits, cat_cards),
+        screen=cfg.make_screen(prob.n_mask_bits, prob.cat_cards),
     )
     if cfg.num_islands > 1:
         ga = nsga2.IslandNSGA2(
             island_cfg=cfg.island_config(),
-            stacked_evaluate=(
-                make_stacked_evaluate() if cfg.stacked_islands else None
-            ),
-            dispatch_evaluate=(
-                dispatch_evaluate if cfg.async_pipeline else None
-            ),
+            stacked_evaluate=evaluate_stacked if cfg.stacked_islands else None,
+            dispatch_evaluate=dispatch_evaluate if cfg.async_pipeline else None,
             **ga_kwargs,
         )
-
-        def run_ga(hook):
-            return ga.run(checkpoint_hook=hook)
     else:
         ga = nsga2.NSGA2(**ga_kwargs)
 
-        def run_ga(hook):
-            if cfg.async_pipeline:
-                return ga.run_async(dispatch_evaluate, checkpoint_hook=hook)
-            return ga.run(checkpoint_hook=hook)
+    def run_ga(hook):
+        return ga.run(checkpoint_hook=hook)
 
     if cfg.hybrid_warm_frac > 0.0 or cfg.hybrid_refine_every > 0:
         engines = ga.islands if cfg.num_islands > 1 else [ga]
@@ -588,7 +623,7 @@ def _run_codesign(cfg: CodesignConfig) -> CodesignResult:
         )
         if cfg.hybrid_refine_every > 0:
             refiner = hybrid.make_refiner(
-                X_tr, y_tr, mlp_cfg.layer_sizes, cfg.adc_bits, axes, hcfg
+                *prob.split[:2], prob.mlp_cfg.layer_sizes, cfg.adc_bits, prob.axes, hcfg
             )
             for eng in engines:
                 eng.set_refiner(refiner, cfg.hybrid_refine_every)
@@ -602,7 +637,7 @@ def _run_codesign(cfg: CodesignConfig) -> CodesignResult:
             evaluations (honest equal-rows accounting vs a pure GA).
             """
             wm, wc = hybrid.warm_start_genomes(
-                X_tr, y_tr, mlp_cfg.layer_sizes, cfg.adc_bits, axes, hcfg
+                *prob.split[:2], prob.mlp_cfg.layer_sizes, cfg.adc_bits, prob.axes, hcfg
             )
             if not wm.shape[0] or k_warm <= 0:
                 return
@@ -649,42 +684,28 @@ def _run_codesign(cfg: CodesignConfig) -> CodesignResult:
         # all-zero categorical genes decode to the default/exact choice of
         # every gene group (po2-8 weights, exact ReLU), so the baseline stays
         # the [7] bespoke circuit whatever axes the search evolves
-        base_cats = np.zeros(
-            (n_seeds, len(chromosome.cat_cardinalities(axes, n_layers))), np.int64
+        _, base_rows = prob.rows(
+            np.ones((n_seeds, prob.n_mask_bits), bool),
+            np.zeros((n_seeds, len(prob.cat_cards)), np.int64),
+            seeds=np.arange(n_seeds, dtype=np.int32),
         )
-        base = chromosome.decode_batch(
-            np.ones((n_seeds, chromosome.n_mask_bits(spec.n_features, cfg.adc_bits)), bool),
-            base_cats, spec.n_features, cfg.adc_bits,
-            axes=axes, n_layers=n_layers,
-        )
-        base_accs = np.asarray(
-            evaluators["pop"](
-                base["masks"], base["weight_bits"], base["act_bits"],
-                base["batch_size"], base["epochs"], base["lr"],
-                np.arange(n_seeds, dtype=np.int32),
-                *_extra_rows(base),
-            )
-        )
-        conv_acc = float(base_accs.max())
+        conv_acc = float(np.asarray(evaluators["pop"](*base_rows)).max())
 
     with spans.span("codesign.result"):
-        dec = chromosome.decode_batch(
-            out["masks"], out["cats"], spec.n_features, cfg.adc_bits,
-            axes=axes, n_layers=n_layers,
-        )
-        front_area, front_power = cost_batch(dec)
+        dec = prob.decode(out["masks"], out["cats"])
+        front_area, front_power = prob.cost_batch(dec)
         front_acc = 1.0 - out["objs"][:, 0]
         return CodesignResult(
             dataset=cfg.dataset,
-            spec=spec,
+            spec=prob.spec,
             front_masks=dec["masks"],
             front_cats=out["cats"],
             front_acc=front_acc,
             front_area=front_area,
             front_power=front_power,
             conv_acc=conv_acc,
-            conv_area=conv_area,
-            conv_power=conv_power,
+            conv_area=prob.conv_area,
+            conv_power=prob.conv_power,
             history=out["history"],
             n_evaluations=int(out["n_evaluations"]),
             n_memo_hits=int(out["n_memo_hits"]),
@@ -692,7 +713,7 @@ def _run_codesign(cfg: CodesignConfig) -> CodesignResult:
             island_history=out.get("island_history"),
             migrations=out.get("migrations"),
             recoveries=recoveries,
-            genome_axes=axes,
+            genome_axes=prob.axes,
         )
 
 
@@ -703,8 +724,8 @@ def make_service_backend(cfg: CodesignConfig, wave_slots: int = 4) -> dict:
     ``wave_slots`` per-request ``(masks, cats)`` batches in, one
     objective array per slot out — so the backend is the stacked-islands
     objective of :func:`run_codesign` rebuilt for a fixed slot count:
-    same genome decode, same crc32 genome seeds, same area pass, same
-    ``trainer.make_island_evaluator`` program.  A genome therefore gets
+    the same ``_problem`` (genome decode, crc32 genome seeds, area pass)
+    and the same ``trainer.make_island_evaluator`` program.  A genome therefore gets
     the exact objective vector here that any campaign with the same
     :meth:`CodesignConfig.memo_fingerprint` computes, which is what makes
     the service's shared memo interchangeable with campaign memos on
@@ -718,64 +739,25 @@ def make_service_backend(cfg: CodesignConfig, wave_slots: int = 4) -> dict:
     ``conv_area`` for reporting.  The stacked
     program is *dispatched* (``island_evaluator.dispatch``) so the
     per-wave area pass runs on the host while the QAT wave trains on
-    device — the same overlap the async campaign pipeline uses.
+    device — the same overlap a search's blocking callback has.
     """
     cfg.validate()
-    X, y, spec = uci_synth.load(cfg.dataset)
-    X_tr, y_tr, X_te, y_te = uci_synth.stratified_split(X, y, 0.7, cfg.seed)
-    mlp_cfg = qat.MLPConfig(
-        layer_sizes=(spec.n_features, spec.hidden, spec.n_classes),
-        adc_bits=cfg.adc_bits,
-    )
-    axes = cfg.axes()
-    n_layers = len(mlp_cfg.layer_sizes) - 1
-    eval_cfg = trainer.EvalConfig(
-        max_steps=cfg.max_steps, step_scale=cfg.step_scale, seed=cfg.seed,
-        use_fused_kernel=cfg.use_fused_kernel, genome_axes=axes,
-    )
+    prob = _problem(cfg)
     island_eval = trainer.make_island_evaluator(
-        X_tr, y_tr, X_te, y_te, mlp_cfg, eval_cfg, num_islands=wave_slots,
+        *prob.split, prob.mlp_cfg, prob.eval_cfg, num_islands=wave_slots,
     )
-    conv_area, _ = area_model.conventional_cost(spec.n_features, cfg.adc_bits)
-    cost_batch, norm_area, _ = _make_cost_batch(axes, cfg.adc_bits, mlp_cfg.layer_sizes)
-
-    def stacked_evaluate(batches):
-        decs = [
-            chromosome.decode_batch(
-                m, c, spec.n_features, cfg.adc_bits,
-                axes=axes, n_layers=n_layers,
-            )
-            for m, c in batches
-        ]
-        resolve_accs = island_eval.dispatch([
-            (d["masks"], d["weight_bits"], d["act_bits"],
-             d["batch_size"], d["epochs"], d["lr"], _genome_seeds(m, c))
-            + _extra_rows(d)
-            for d, (m, c) in zip(decs, batches)
-        ])
-        # host-side area pass, overlapped with the in-flight stacked wave
-        areas = [cost_batch(d)[0] for d in decs]
-        accs = resolve_accs()
-        return [
-            np.stack([1.0 - np.asarray(a), ar / norm_area], axis=1)
-            if len(ar) else None
-            for a, ar in zip(accs, areas)
-        ]
-
-    n_mask_bits = chromosome.n_mask_bits(spec.n_features, cfg.adc_bits)
-    cat_cards = tuple(chromosome.cat_cardinalities(axes, n_layers))
     screen_factory = (
-        (lambda: cfg.make_screen(n_mask_bits, cat_cards))
+        (lambda: cfg.make_screen(prob.n_mask_bits, prob.cat_cards))
         if cfg.surrogate
         else None
     )
     return {
-        "stacked_evaluate": stacked_evaluate,
+        "stacked_evaluate": lambda batches: prob.wave(batches, island_eval.dispatch),
         "fingerprint": cfg.memo_fingerprint(),
-        "n_mask_bits": n_mask_bits,
-        "cat_cardinalities": cat_cards,
-        "spec": spec,
-        "conv_area": conv_area,
+        "n_mask_bits": prob.n_mask_bits,
+        "cat_cardinalities": prob.cat_cards,
+        "spec": prob.spec,
+        "conv_area": prob.conv_area,
         "screen_factory": screen_factory,
     }
 

@@ -29,8 +29,8 @@ Three layers, composed by :class:`EvalService`:
   serial resource; admission control bounds everything else.
 * :class:`EvalService` — request lifecycle.  Each submitted
   :class:`SearchRequest` runs a private ``NSGA2`` engine on its own
-  thread (``run_async`` with :meth:`NSGA2.dispatch_pool` as the
-  per-request client of the shared scheduler), gated by
+  thread (``NSGA2.run``, whose blocking callback submits to the shared
+  scheduler and waits on the wave), gated by
   ``runtime.admission`` (FIFO ``max_active`` slots + bounded queue +
   per-request deadline watchdog).
 
@@ -297,11 +297,10 @@ class WaveScheduler:
     ) -> Callable[[], np.ndarray]:
         """Enqueue one batch; returns a blocking zero-arg resolve().
 
-        Exactly the ``dispatch_evaluate`` contract of
-        :meth:`NSGA2.dispatch_pool` / :meth:`NSGA2.run_async`: the batch
-        is in the next wave's hands NOW, the caller blocks only when it
-        resolves — which is what lets many request threads' batches pile
-        into one wave while each engine sits at its own commit point.
+        The ``dispatch_evaluate`` contract of :meth:`NSGA2.dispatch_pool`:
+        the batch is in the next wave's hands NOW, the caller blocks only
+        when it resolves — which is what lets many request threads'
+        batches pile into one wave while each engine waits on its own.
         """
         if self._stop.is_set():
             raise RuntimeError("WaveScheduler is stopped")
@@ -552,10 +551,11 @@ class EvalService:
             start_memo = (
                 req.memo if req.memo is not None else self.shared.snapshot()
             )
+            dispatch = self._make_dispatch(req)
             engine = nsga2.NSGA2(
                 self.n_mask_bits,
                 self.cat_cardinalities,
-                evaluate=self._no_sync_evaluate,
+                evaluate=lambda m, c: dispatch(m, c)(),
                 cfg=req.ga,
                 memo=start_memo,
                 screen=(
@@ -564,7 +564,7 @@ class EvalService:
                     else None
                 ),
             )
-            out = engine.run_async(self._make_dispatch(req))
+            out = engine.run()
             res.result = out
             res.n_evaluations = engine.n_evaluations
             res.n_memo_hits = engine.n_memo_hits
@@ -592,13 +592,6 @@ class EvalService:
             return self.scheduler.submit(masks, cats)
 
         return dispatch_evaluate
-
-    @staticmethod
-    def _no_sync_evaluate(masks, cats):
-        raise RuntimeError(
-            "service engines evaluate through the wave scheduler only; "
-            "the synchronous callback must never fire"
-        )
 
     def result(self, request_id: str, timeout: float | None = None) -> SearchResult:
         """Join one request and return its result (or error) record.

@@ -1,8 +1,9 @@
 """The ONE evaluation pipeline every driver schedules over (PR 9).
 
 PRs 1-8 grew four driver schedules around the memoized objective —
-blocking ``NSGA2._evaluate``, the stacked island wave, the async
-``dispatch_pool`` closures, and the eval-service ``WaveScheduler`` —
+blocking ``NSGA2._evaluate``, the stacked island wave, the dispatched
+island wave (``dispatch_pool`` closures), and the eval-service
+``WaveScheduler`` —
 plus the elastic replay path, and each of them re-stated the same two
 memo halves inline.  This module is the extraction: the plan/dedupe and
 commit/gather primitives exist HERE and nowhere else, and every driver

@@ -20,70 +20,70 @@ what ``benchmarks/ga_runtime.py`` uses as the re-evaluation baseline.
 
 ``history`` records per-generation telemetry: front size, best objectives,
 rows actually evaluated (``n_evals``), memo hits, evaluation wall-clock
-(``eval_s``) and total generation wall-clock (``gen_s``), the durations of
-the generation's ``nsga2.evaluate`` and ``nsga2.generation`` spans
-(``core.spans``).
+(``eval_s``) and total generation wall-clock (``gen_s``): for one
+population the durations of the generation's ``nsga2.evaluate`` and
+``nsga2.generation`` spans (``core.spans``); on islands each ``gen_s`` is
+the island's equal share of the wave (:meth:`IslandNSGA2.run`).
 
 Begin/commit phase contract: ``setup`` and ``step`` are each the exact
 composition of a ``*_begin`` phase and a ``*_commit`` phase with the
-evaluation in between.  The contract every outer driver (stacked islands,
-async pipelining) relies on is:
+evaluation in between.  The contract the island loop and the evaluation
+service rely on is:
 
 * ``setup_begin`` / ``step_begin`` consume ALL of the generation's
   host-side RNG (initialisation or variation) and return the pool to
   evaluate — no randomness is drawn anywhere else, so a driver may
   reorder *when* pools are evaluated without perturbing any stream;
 * ``plan_pool`` / ``commit_pool`` are the two halves of the memoized
-  ``_evaluate`` (with ``plan_unseen`` / ``commit_plan`` as their
-  screen-less compatibility spellings): planning reads the memo (plus an
-  optional cross-island ``claimed`` set) and picks the first-seen rows,
-  optionally splitting them through a pluggable screen stage
-  (``core.evalpipe.ScreenStage`` — ``core.surrogate`` is the real one);
-  committing writes the memo in plan order and settles the
-  ``n_evaluations`` / ``n_memo_hits`` / ``n_deferred`` counters.  The
-  dedupe walk and the write+gather sequence themselves live in
-  ``core.evalpipe`` — every driver below is a thin schedule over that
-  pipeline.  Plan order == commit order == memo insertion order;
+  ``_evaluate``: planning reads the memo (plus an optional cross-island
+  ``claimed`` set) and picks the first-seen rows, optionally splitting
+  them through a pluggable screen stage (``core.evalpipe.ScreenStage`` —
+  ``core.surrogate`` is the real one); committing writes the memo in
+  plan order and settles the ``n_evaluations`` / ``n_memo_hits`` /
+  ``n_deferred`` counters.  The dedupe walk and the write+gather
+  sequence themselves live in ``core.evalpipe``.  Plan order == commit
+  order == memo insertion order;
 * ``setup_commit`` / ``step_commit`` run environmental selection and
   telemetry on the evaluated pool and are the only phases that mutate
   ``pop``/``objs``/``rank``/``crowd``.
 
 Because objectives are a pure function of the genome (training seeds are
-derived from genome bytes upstream), any driver that calls begins, plans,
-commits in the same per-engine order as the monolithic loop — no matter
-how it batches, stacks, or overlaps the evaluations in between — is
-bit-for-bit the reference: same RNG streams, same memo contents and
-insertion order, same counters, same front.  The stacked island driver
-and the async pipeline driver below are both instances of this argument.
+derived from genome bytes upstream), any schedule that calls begins,
+plans, commits in the same per-engine order as the plain loop — no
+matter how it batches, stacks, or overlaps the evaluations in between —
+is bit-for-bit the reference: same RNG streams, same memo contents and
+insertion order, same counters, same front.
 
-Async generation pipelining (``IslandConfig.async_pipeline``): instead of
-a blocking ``evaluate`` callback, the driver takes ``dispatch_evaluate``
-— a callback that *launches* the device program for a batch without
-waiting on it (JAX dispatches asynchronously on every backend) and
-returns a zero-argument ``resolve()`` that blocks
-(``jax.block_until_ready``) and yields the objectives.  The island
-driver dispatches island *i*'s unseen batch and immediately runs island
-*i+1*'s host-side variation and memo planning while the devices chew on
-islands ``0..i``; commits then run in island order, blocking only where
-results are not yet ready.  The host-side GA latency of K−1 islands
-hides behind device compute; nothing about *what* is computed changes.
+One generation loop per engine.  ``NSGA2.run`` steps one population
+through the blocking ``evaluate`` callback, which may itself launch a
+device program, do host work while it runs, and only then block
+(``core.codesign``'s does).  Island model (:class:`IslandNSGA2`): K
+independent sub-populations, each a plain :class:`NSGA2` with its own
+RNG stream, advance in lock-step under ``IslandNSGA2.run``: every
+island's begin, one evaluation of the wave of K pools, every island's
+commit in island order; every ``IslandConfig.migration_interval``
+generations the top-crowding-distance Pareto-front genomes of each
+island then migrate ring-wise to its neighbour, deduplicated against the
+destination population by the same genome-bytes keys the memo uses.  All
+islands share ONE evaluation memo, so a migrant — already trained on its
+source island — costs zero QAT rows on arrival.  The wave is evaluated
+on one of three schedules (:meth:`IslandNSGA2._evaluate_wave`), all
+bit-for-bit the same search:
 
-Island model (:class:`IslandNSGA2`): K independent sub-populations, each a
-plain :class:`NSGA2` with its own RNG stream, advance in lock-step; every
-``IslandConfig.migration_interval`` generations the top-crowding-distance
-Pareto-front genomes of each island migrate ring-wise to its neighbour,
-deduplicated against the destination population by the same genome-bytes
-keys the memo uses.  All islands share ONE evaluation memo, so a migrant —
-already trained on its source island — costs zero QAT rows on arrival.
-``run()`` returns the merged, deduplicated cross-island Pareto front plus
-per-island histories and a migration log.  With ``num_islands=1`` the
-driver is the identity wrapper: it replays the exact single-population
-``NSGA2.run()`` (same RNG stream, same front, bit for bit).  With
-``IslandConfig.stacked`` the driver gathers every island's unseen-genome
-batch and evaluates them as ONE cross-island SPMD program per generation
-(``core.trainer.make_island_evaluator``) — bit-for-bit identical results
-to the sequential reference driver, which remains the single-device
-fallback.
+* sequential (default): each island's pool through its own blocking
+  ``_evaluate``, in island order — the single-device reference;
+* stacked (``IslandConfig.stacked``): every pool planned against the
+  shared memo, then ONE cross-island batch through ``stacked_evaluate``
+  (``core.trainer.make_island_evaluator``, one SPMD program);
+* dispatched (``IslandConfig.async_pipeline``): each pool planned and
+  launched through ``dispatch_evaluate`` without blocking (JAX
+  dispatches asynchronously), then resolved in island order — later
+  islands' planning runs while earlier islands' programs train.
+
+``IslandNSGA2.run()`` returns the merged, deduplicated cross-island
+Pareto front plus per-island histories and a migration log.  With
+``num_islands=1`` it replays the exact single-population ``NSGA2.run()``
+(same RNG stream, same front, bit for bit).
 
 Implements fast non-dominated sort and crowding distance exactly as the
 original paper; minimisation on every objective.
@@ -266,10 +266,7 @@ class NSGA2Config:
     seed: int = 0
     memoize: bool = True  # cache objective vectors by genome bytes
     # seed-population mask-density band: individuals draw their keep
-    # probability uniformly from this range.  The default spans the whole
-    # useful spectrum; the island driver hands each island a contiguous
-    # slice so the merged initial coverage matches one large population's
-    # spread (stratified/heterogeneous islands)
+    # probability uniformly from this range (the whole useful spectrum)
     init_density: tuple[float, float] = (0.12, 1.0)
 
 
@@ -309,7 +306,7 @@ class NSGA2:
         objectives are silently wrong.
 
         ``memo_lock`` guards the memo dict and its counters: each of the
-        plan/commit halves (:meth:`plan_unseen`, :meth:`commit_plan`) runs
+        plan/commit halves (:meth:`plan_pool`, :meth:`commit_pool`) runs
         under it, and it is NEVER held across an evaluation, so engines
         driven from different threads against one aliased memo dict (the
         evaluation service) interleave at batch granularity without
@@ -382,8 +379,7 @@ class NSGA2:
         The blocking schedule over the evaluation pipeline: plan (+
         screen) via :meth:`plan_pool`, dispatch the train rows through
         the synchronous callback, commit via :meth:`commit_pool` — the
-        same stages every other driver (stacked, async, service wave)
-        reorders but never re-implements.
+        same stages the island schedules reorder but never re-implement.
         """
         if not self.cfg.memoize:
             self.n_evaluations += masks.shape[0]
@@ -468,12 +464,10 @@ class NSGA2:
     # are themselves each split into a ``*_begin`` phase (variation — all
     # host-side RNG consumption) and a ``*_commit`` phase (environmental
     # selection + telemetry), with the evaluation in between, so the
-    # stacked island driver can gather every island's pool, dedupe the
-    # unseen genomes across islands against the ONE shared memo, submit a
-    # single cross-island SPMD batch, and only then commit each island.
-    # ``run``/``step``/``setup`` are the exact compositions of their
-    # phases — the RNG stream is consumed in the same order as the
-    # original monolithic loop, so results are bit-for-bit unchanged.
+    # island loop can run every island's begin, evaluate the wave of K
+    # pools against the ONE shared memo on any schedule, and only then
+    # commit each island.  ``run``/``step``/``setup`` are the exact
+    # compositions of their phases.
 
     def setup_begin(self) -> tuple[np.ndarray, np.ndarray]:
         """Draw the generation-0 pool; returns its (masks, cats)."""
@@ -671,32 +665,7 @@ class NSGA2:
             )
             return evalpipe.gather_rows(plan.keys, self._memo, self._deferred)
 
-    # -- compatibility spellings of the two halves (screen-less) -------------
-
-    def plan_unseen(
-        self,
-        masks: np.ndarray,
-        cats: np.ndarray,
-        claimed: set[bytes] | None = None,
-    ) -> tuple[list[bytes], dict[bytes, int]]:
-        """The screen-less plan half as a ``(keys, unseen)`` pair."""
-        keys = genome_keys(masks, cats)
-        with self._memo_lock:
-            unseen = evalpipe.plan_rows(self._memo, keys, claimed)
-        return keys, unseen
-
-    def commit_plan(
-        self,
-        keys: list[bytes],
-        unseen: dict[bytes, int],
-        objs: np.ndarray | None,
-    ) -> np.ndarray:
-        """The screen-less commit half (see :meth:`commit_pool`)."""
-        return self.commit_pool(
-            evalpipe.PoolPlan(keys=keys, train=dict(unseen)), objs
-        )
-
-    # -- async dispatch (pipelined drivers) ----------------------------------
+    # -- async dispatch (the dispatched island schedule) ----------------------
 
     def dispatch_pool(
         self,
@@ -719,17 +688,9 @@ class NSGA2:
         objectives are ready and returns the full-pool ``(P, M)`` matrix,
         exactly what ``_evaluate`` would have returned.  ``claimed`` is
         updated in place at plan time, so a driver can dispatch several
-        engines' pools back to back before resolving any of them.
+        engines' pools back to back before resolving any of them.  Needs
+        the memo pipeline (``cfg.memoize``), as the island loop enforces.
         """
-        if not self.cfg.memoize:
-            n = int(masks.shape[0])
-            resolve_rows = dispatch_evaluate(masks, cats)
-
-            def resolve_naive() -> np.ndarray:
-                self.n_evaluations += n
-                return np.asarray(resolve_rows(), dtype=np.float64)
-
-            return resolve_naive
         with self._memo_lock:
             # plan + claim atomically: a driver dispatching several engines'
             # pools from different threads must not let two pools claim the
@@ -746,44 +707,6 @@ class NSGA2:
             return self.commit_pool(plan, objs)
 
         return resolve
-
-    def run_async(
-        self,
-        dispatch_evaluate: Callable[
-            [np.ndarray, np.ndarray], Callable[[], np.ndarray]
-        ],
-        checkpoint_hook: Callable | None = None,
-    ) -> dict:
-        """The async-dispatch single-population driver.
-
-        Structurally :meth:`run` with ``_evaluate`` split into dispatch
-        (non-blocking launch) and resolve (block at commit time): the
-        host-side tail of the objective — whatever ``dispatch_evaluate``
-        computes after launching the device program, e.g. the codesign
-        area pass — overlaps the device compute instead of serialising
-        behind it.  A single population has no other host work to hide
-        (generation g+1's variation needs generation g's selection), so
-        the begin → dispatch → resolve → commit order — and therefore the
-        result, bit for bit — is exactly the synchronous loop's; the
-        cross-engine overlap lives in :meth:`IslandNSGA2._run_async`.
-        """
-        if self.pop is None:
-            with spans.span("nsga2.setup"):
-                masks, cats = self.setup_begin()
-                self.setup_commit(
-                    self.dispatch_pool(masks, cats, dispatch_evaluate)()
-                )
-            if checkpoint_hook is not None:
-                checkpoint_hook(self, 0)
-        for _ in range(self.gen, self.cfg.n_generations):
-            allm, allc = self.step_begin()
-            with spans.span("nsga2.evaluate") as ev:
-                resolve = self.dispatch_pool(allm, allc, dispatch_evaluate)
-                allo = resolve()
-            self.step_commit(allo, ev.dur)
-            if checkpoint_hook is not None:
-                checkpoint_hook(self, self.gen)
-        return self.result()
 
     def result(self) -> dict:
         """Final Pareto front + telemetry of the current population."""
@@ -1085,29 +1008,22 @@ class IslandConfig:
     migration_interval: int = 3
     migration_size: int = 2
     topology: str = "ring"
+    # The two flags pick how IslandNSGA2.run evaluates each wave of K
+    # pools (IslandNSGA2._evaluate_wave); neither set is the sequential
+    # schedule, the single-device reference.  Results are bit-for-bit
+    # identical on all three.
     # stacked=True evaluates all K islands' unseen genomes as ONE
-    # cross-island SPMD batch per generation (lock-step driver) instead of
-    # stepping the islands sequentially; requires NSGA2Config.memoize.
-    # Results are bit-for-bit identical to the sequential loop — which
-    # stays the reference implementation and single-device fallback.
+    # cross-island SPMD batch per generation; requires NSGA2Config.memoize.
     stacked: bool = False
-    # async_pipeline=True overlaps host-side variation with device-side
-    # evaluation: island i's unseen batch is dispatched as a non-blocking
-    # device program and island i+1's variation/planning runs while it
-    # evaluates; the host blocks (jax.block_until_ready) only at commit
-    # time.  Requires NSGA2Config.memoize (same cross-island claimed-set
-    # dedupe as stacked) and is mutually exclusive with stacked: stacked
-    # fills K device groups with one wave, async hides host latency behind
-    # in-flight per-island programs — two answers to device idleness that
-    # cannot both govern when a wave is submitted.  Results are bit-for-bit
-    # identical to the sequential reference either way.
+    # async_pipeline=True dispatches each island's unseen batch as a
+    # non-blocking device program, so island i+1's planning runs while
+    # island i evaluates; the host blocks (jax.block_until_ready) only at
+    # commit time.  Requires NSGA2Config.memoize (same cross-island
+    # claimed-set dedupe as stacked) and is mutually exclusive with
+    # stacked: stacked fills K device groups with one wave, async hides
+    # host latency behind in-flight per-island programs — two answers to
+    # device idleness that cannot both govern when a wave is submitted.
     async_pipeline: bool = False
-    # stratify_init hands each island a contiguous slice of the seed
-    # mask-density band instead of the full spectrum (heterogeneous
-    # islands).  Off by default: measured on the co-design workload the
-    # full-band seed + migration explores better than hard density
-    # niching (benchmarks/ga_runtime.run_islands sweeps both)
-    stratify_init: bool = False
 
     def __post_init__(self):
         if self.num_islands < 1:
@@ -1141,23 +1057,17 @@ class IslandNSGA2:
     island (zero QAT rows), and the merged memo is what
     ``core.memo_store`` persists.
 
-    Three drivers share the same migration machinery.  The sequential
-    reference (``IslandConfig.stacked=False``) steps islands one after
-    another, each island's evaluator itself population-sharded
-    (``parallel.sharding.population_rules``).  The stacked driver
-    (``stacked=True``) runs every island's variation phase, dedupes the
-    unseen genomes ACROSS islands against the shared memo (island order —
-    the same order the sequential loop trains them in), and submits one
-    cross-island batch per generation through ``stacked_evaluate``
+    One generation loop (:meth:`run`) over three evaluation schedules
+    (:meth:`_evaluate_wave`, picked by ``IslandConfig``): sequential,
+    each island's pool through its own blocking evaluator, itself
+    population-sharded (``parallel.sharding.population_rules``); stacked,
+    one cross-island batch per generation through ``stacked_evaluate``
     (``core.trainer.make_island_evaluator`` lowers it onto the ``(island,
-    population)`` device-group mesh of ``parallel.sharding.island_mesh``).
-    The async pipeline driver (``async_pipeline=True``) keeps per-island
-    programs but launches each without blocking via ``dispatch_evaluate``
-    and overlaps the next island's host-side variation/planning with the
-    in-flight device work, blocking only at commit time
-    (:meth:`_run_async`).  All three drivers produce bit-for-bit
-    identical results — RNG streams, memo contents and insertion order,
-    per-island counters, merged front.
+    population)`` device-group mesh of ``parallel.sharding.island_mesh``);
+    dispatched, per-island programs launched without blocking through
+    ``dispatch_evaluate`` and resolved at commit time.  All three produce
+    bit-for-bit identical results — RNG streams, memo contents and
+    insertion order, per-island counters, merged front.
 
     ``run()`` returns the merged, genome-deduplicated Pareto front over
     the final island populations (symmetric with the single-population
@@ -1235,26 +1145,12 @@ class IslandNSGA2:
         self._deferred: dict[bytes, np.ndarray] = {}
         self._screen = screen
         self.islands: list[NSGA2] = []
-        K = island_cfg.num_islands
-        lo, hi = cfg.init_density
-        for i in range(K):
-            # optional stratified initialization: island i seeds its
-            # population in the i-th contiguous slice of the mask-density
-            # band (heterogeneous islands).  K=1 or stratify_init=False
-            # keeps the full band — bit-for-bit the single engine's init.
-            if island_cfg.stratify_init:
-                band = (lo + (hi - lo) * i / K, lo + (hi - lo) * (i + 1) / K)
-            else:
-                band = (lo, hi)
+        for i in range(island_cfg.num_islands):
             isl = NSGA2(
                 n_mask_bits,
                 cat_cardinalities,
                 evaluate,
-                cfg=dataclasses.replace(
-                    cfg,
-                    seed=cfg.seed + i * ISLAND_SEED_STRIDE,
-                    init_density=band,
-                ),
+                cfg=dataclasses.replace(cfg, seed=cfg.seed + i * ISLAND_SEED_STRIDE),
             )
             if cfg.memoize:
                 isl._memo = self._memo  # alias, not copy: one global cache
@@ -1420,7 +1316,22 @@ class IslandNSGA2:
         }
 
     def run(self, checkpoint_hook: Callable | None = None) -> dict:
-        """Run (or resume) the configured driver.
+        """Run (or resume) the island loop.
+
+        Setup wave, then per generation: every island's ``step_begin``,
+        one evaluation of the wave of K pools (:meth:`_evaluate_wave`),
+        every island's ``step_commit`` in island order, migration,
+        aggregation, and the checkpoint hook.  Running all K begins before
+        any evaluation changes no stream: each begin draws only its own
+        island's RNG, and the memo is read only by the plans inside the
+        wave.
+
+        Telemetry: each island's ``eval_s`` is what the schedule spent on
+        that island (its own evaluation, its resolve, or an equal share
+        of the stacked program); each island's ``gen_s`` is an equal share
+        of the wave's wall time (its generation span covers every
+        island's work), so the aggregated ``history`` sums to the true
+        wall time of each generation.
 
         ``checkpoint_hook(driver, gens_done)`` fires at every generation
         boundary — after setup (``gens_done=0``) and after each completed
@@ -1429,78 +1340,27 @@ class IslandNSGA2:
         :meth:`set_state` continues from the recorded generation; a fresh
         driver is bit-for-bit the original loop.
         """
-        if self.island_cfg.async_pipeline:
-            return self._run_async(checkpoint_hook)
-        if self.island_cfg.stacked:
-            return self._run_stacked(checkpoint_hook)
-        return self._run_sequential(checkpoint_hook)
-
-    def _run_sequential(self, checkpoint_hook: Callable | None = None) -> dict:
-        """Reference driver: islands step one after another.
-
-        Single-device fallback and the ground truth the stacked driver is
-        tested bit-for-bit against.
-        """
-        icfg = self.island_cfg
         if self.islands[0].pop is None:
-            for isl in self.islands:
-                isl.setup()
+            with spans.span("nsga2.setup"):
+                pools = [isl.setup_begin() for isl in self.islands]
+                allos, _ = self._evaluate_wave(pools)
+                for isl, allo in zip(self.islands, allos):
+                    isl.setup_commit(allo)
             if checkpoint_hook is not None:
                 checkpoint_hook(self, 0)
-        for gen in range(self.gens_done, self.cfg.n_generations):
-            recs = [isl.step() for isl in self.islands]
-            if (gen + 1) % icfg.migration_interval == 0 and (
-                gen + 1
-            ) < self.cfg.n_generations:
-                self._migrate(gen)
-            self.agg_history.append(self._aggregate(gen, recs))
-            if checkpoint_hook is not None:
-                checkpoint_hook(self, gen + 1)
-        out = self._merged_result()
-        out["history"] = self.agg_history
-        return out
-
-    def _run_stacked(self, checkpoint_hook: Callable | None = None) -> dict:
-        """Lock-step driver: ONE cross-island evaluation per generation.
-
-        Every island runs its variation phase first, then the driver plans
-        the unseen genomes of all K pools against the shared memo (in
-        island order, so a genome born on two islands this generation is
-        owned by the lower-indexed one — exactly the order the sequential
-        loop trains it in), submits a single stacked batch, and commits
-        each island.  RNG streams, memo contents/insertion order, counters
-        and the merged front are bit-for-bit the sequential driver's.
-        """
-        icfg = self.island_cfg
-        if self.islands[0].pop is None:
-            pools = [isl.setup_begin() for isl in self.islands]
-            allos, _ = self._evaluate_stacked(pools)
-            for isl, allo in zip(self.islands, allos):
-                isl.setup_commit(allo)
-            if checkpoint_hook is not None:
-                checkpoint_hook(self, 0)
+        K = len(self.islands)
         for gen in range(self.gens_done, self.cfg.n_generations):
             t_wave = time.perf_counter()
             pools = [isl.step_begin() for isl in self.islands]
-            allos, eval_s = self._evaluate_stacked(pools)
-            # the K islands share ONE stacked program: attribute an equal
-            # share to each so aggregated eval_s sums to the true wall time
-            share = eval_s / len(self.islands)
+            allos, eval_s = self._evaluate_wave(pools)
             recs = [
-                isl.step_commit(allo, share)
-                for isl, allo in zip(self.islands, allos)
+                isl.step_commit(allo, e)
+                for isl, allo, e in zip(self.islands, allos, eval_s)
             ]
-            # same correction for gen_s: each island's generation span
-            # covers the whole K-island wave (every begin phase, the shared program,
-            # the earlier commits), so the raw per-island number is ~K x
-            # the truth and their sum ~K^2 x.  Overwrite with an equal
-            # share of the measured wave so the aggregated history's
-            # gen_s — what run_islands compares drivers by — sums to the
-            # actual generation wall clock, exactly like eval_s.
-            wave_share = (time.perf_counter() - t_wave) / len(self.islands)
+            wave_share = round((time.perf_counter() - t_wave) / K, 4)
             for rec in recs:
-                rec["gen_s"] = round(wave_share, 4)
-            if (gen + 1) % icfg.migration_interval == 0 and (
+                rec["gen_s"] = wave_share
+            if (gen + 1) % self.island_cfg.migration_interval == 0 and (
                 gen + 1
             ) < self.cfg.n_generations:
                 self._migrate(gen)
@@ -1511,107 +1371,65 @@ class IslandNSGA2:
         out["history"] = self.agg_history
         return out
 
-    def _run_async(self, checkpoint_hook: Callable | None = None) -> dict:
-        """Pipelined driver: host variation overlaps device evaluation.
-
-        Per generation, islands are walked in index order; each island
-        runs its variation phase (host RNG) and memo planning, then its
-        unseen batch is *launched* through ``dispatch_evaluate`` without
-        waiting — so while the devices evaluate islands ``0..i``, the
-        host is already varying and planning island ``i+1``.  Commits
-        then run in island order, each blocking only until its own batch
-        is ready (``jax.block_until_ready`` inside the resolve closure).
-
-        Bit-for-bit identity with the sequential reference holds by the
-        begin/commit contract (module docstring): per-island RNG streams
-        are independent, so interleaving begins across islands changes no
-        draws; planning walks islands in index order against the shared
-        memo + the ``claimed`` set (a genome born on two islands this
-        wave is owned by the lower-indexed one — the exact row the
-        sequential loop trains); and commits run in the same island
-        order, so memo contents, insertion order, and per-island counters
-        all match.  Only *when the host blocks* moves.
-
-        Telemetry: each island's ``eval_s`` is the time its commit
-        actually spent blocked+settling (island 0 absorbs most of the
-        wave; later islands resolve nearly free), so the aggregated
-        ``eval_s`` sums to the host's true blocked time — the number the
-        pipeline shrinks.  ``gen_s`` gets the same equal-share-of-wave
-        correction as the stacked driver so the aggregated history sums
-        to real wall clock.
-        """
-        icfg = self.island_cfg
-
-        def dispatch_wave(begin):
-            claimed: set[bytes] = set()
-            pending = []
-            for isl in self.islands:
-                masks, cats = begin(isl)  # host variation, own RNG stream
-                pending.append(
-                    isl.dispatch_pool(masks, cats, self._dispatch_fn, claimed)
-                )
-            return pending
-
-        if self.islands[0].pop is None:
-            for isl, resolve in zip(
-                self.islands, dispatch_wave(lambda isl: isl.setup_begin())
-            ):
-                isl.setup_commit(resolve())
-            if checkpoint_hook is not None:
-                checkpoint_hook(self, 0)
-        for gen in range(self.gens_done, self.cfg.n_generations):
-            t_wave = time.perf_counter()
-            pending = dispatch_wave(lambda isl: isl.step_begin())
-            recs = []
-            for isl, resolve in zip(self.islands, pending):
-                t0 = time.perf_counter()
-                allo = resolve()  # blocks iff this batch is still in flight
-                recs.append(isl.step_commit(allo, time.perf_counter() - t0))
-            # a perf_counter pair, not the spans: one wave is spread over K islands
-            wave_share = (time.perf_counter() - t_wave) / len(self.islands)
-            for rec in recs:
-                rec["gen_s"] = round(wave_share, 4)
-            if (gen + 1) % icfg.migration_interval == 0 and (
-                gen + 1
-            ) < self.cfg.n_generations:
-                self._migrate(gen)
-            self.agg_history.append(self._aggregate(gen, recs))
-            if checkpoint_hook is not None:
-                checkpoint_hook(self, gen + 1)
-        out = self._merged_result()
-        out["history"] = self.agg_history
-        return out
-
-    def _evaluate_stacked(
+    def _evaluate_wave(
         self, pools: list[tuple[np.ndarray, np.ndarray]]
-    ) -> tuple[list[np.ndarray], float]:
-        """Plan → submit one stacked batch → commit, in island order.
+    ) -> tuple[list[np.ndarray], list[float]]:
+        """Evaluate one wave of K pools on the configured schedule.
 
-        Returns each island's full-pool objective matrix plus the
-        evaluation wall time.  Planning walks the islands in index order
-        against the shared memo and a ``claimed`` set, so duplicate
-        genomes across islands train once; commits happen in the same
-        order, so memo insertion order matches the sequential loop's.
+        Returns each island's full-pool objective matrix and ``eval_s``.
+        Every schedule plans and commits the islands in index order
+        against the shared memo, so a genome born on two islands in one
+        generation trains once, on the lower-indexed island, and memo
+        insertion order is the sequential schedule's:
+
+        * stacked: plan every pool (a ``claimed`` set carries the keys
+          earlier islands own), submit ONE batch to ``stacked_evaluate``,
+          commit; each island gets an equal share of the program's time;
+        * dispatched: plan and launch each pool (``dispatch_pool``, same
+          ``claimed`` set), then resolve and commit in order; each island
+          gets the time its resolve blocked and settled;
+        * sequential: each island's blocking ``_evaluate`` under its own
+          ``nsga2.evaluate`` span, so island i+1 plans after island i's
+          commit (a surrogate screen refits on the grown memo; with
+          ``memoize=False`` every row trains).
         """
-        claimed: set[bytes] = set()
-        plans: list[evalpipe.PoolPlan] = []
-        for isl, (m, c) in zip(self.islands, pools):
-            plan = isl.plan_pool(m, c, claimed)
-            claimed.update(plan.first_seen)
-            plans.append(plan)
-        t0 = time.perf_counter()
-        if any(plan.train for plan in plans):
-            batches = [
-                plan.take(m, c) for (m, c), plan in zip(pools, plans)
+        K = len(self.islands)
+        if self.island_cfg.stacked:
+            claimed: set[bytes] = set()
+            plans: list[evalpipe.PoolPlan] = []
+            for isl, (m, c) in zip(self.islands, pools):
+                plan = isl.plan_pool(m, c, claimed)
+                claimed.update(plan.first_seen)
+                plans.append(plan)
+            t0 = time.perf_counter()
+            if any(plan.train for plan in plans):
+                objs = self._stacked_evaluate_fn(
+                    [plan.take(m, c) for (m, c), plan in zip(pools, plans)]
+                )
+            else:
+                objs = [None] * K
+            share = (time.perf_counter() - t0) / K
+            allos = [
+                isl.commit_pool(plan, o)
+                for isl, plan, o in zip(self.islands, plans, objs)
             ]
-            objs = self._stacked_evaluate_fn(batches)
-        else:
-            objs = [None] * len(self.islands)
-        eval_s = time.perf_counter() - t0
-        allos = [
-            isl.commit_pool(plan, o)
-            for isl, plan, o in zip(self.islands, plans, objs)
-        ]
+            return allos, [share] * K
+        allos, eval_s = [], []
+        if self.island_cfg.async_pipeline:
+            claimed = set()
+            pending = [
+                isl.dispatch_pool(m, c, self._dispatch_fn, claimed)
+                for isl, (m, c) in zip(self.islands, pools)
+            ]
+            for resolve in pending:
+                t0 = time.perf_counter()
+                allos.append(resolve())  # blocks iff still in flight
+                eval_s.append(time.perf_counter() - t0)
+            return allos, eval_s
+        for isl, (m, c) in zip(self.islands, pools):
+            with spans.span("nsga2.evaluate") as ev:
+                allos.append(isl._evaluate(m, c))
+            eval_s.append(ev.dur)
         return allos, eval_s
 
     def _merged_result(self) -> dict:

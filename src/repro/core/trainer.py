@@ -51,9 +51,8 @@ asynchronously on every backend, so the caller decides when to pay the
 synchronisation.  The synchronous engine converts immediately;
 ``evaluate.dispatch(...)`` instead returns a zero-arg ``resolve()`` that
 performs the ``jax.block_until_ready`` + host transfer, which is what
-lets the async pipeline driver (``core.nsga2.IslandNSGA2._run_async``)
-run the next island's host-side variation while this batch trains on
-device.  Nothing else differs between the two entry points: same
+lets the dispatched island schedule (``core.nsga2.IslandNSGA2._evaluate_wave``)
+plan the next island's pool while this batch trains on device.  Nothing else differs between the two entry points: same
 padding, same sharding, same compiled program, same values.
 """
 
@@ -375,9 +374,9 @@ def make_population_evaluator(
         un-synchronised ``jax.Array``\\ s), so dispatching is just calling
         it — the device starts immediately — and deferring the host
         transfer into ``resolve()``, where ``jax.block_until_ready``
-        makes the synchronisation point explicit.  The async pipeline
-        driver dispatches every island's batch this way and resolves at
-        commit time (``core.nsga2.IslandNSGA2._run_async``).
+        makes the synchronisation point explicit.  The dispatched island
+        schedule launches every island's batch this way and resolves at
+        commit time (``core.nsga2.IslandNSGA2._evaluate_wave``).
         """
         acc = evaluate(*args)
 

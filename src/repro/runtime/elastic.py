@@ -138,8 +138,7 @@ class ElasticGARunner:
     ``driver`` is anything with the ``state_dict`` / ``set_state`` /
     ``gens_done`` protocol (``core.nsga2.NSGA2`` or ``IslandNSGA2``);
     ``run_fn(checkpoint_hook)`` enters its run loop — the indirection
-    lets the caller pick ``run`` vs ``run_async`` and close over its own
-    dispatch callback.  At every generation boundary the runner feeds the
+    lets the caller wrap the loop (e.g. seed warm genomes first).  At every generation boundary the runner feeds the
     latest generation wall-time to the watchdog (a straggler event makes
     the next checkpoint urgent, an eviction re-meshes without rollback),
     snapshots the driver in memory, and invokes ``checkpoint_cb(driver,

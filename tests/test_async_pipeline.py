@@ -1,4 +1,4 @@
-"""Async generation pipelining: bit-for-bit identity with the sync driver.
+"""Dispatched island schedule: bit-for-bit identity with the sequential one.
 
 The fast tests (``ci`` marker) drive :class:`core.nsga2.NSGA2` /
 :class:`core.nsga2.IslandNSGA2` with cheap analytic objectives and a
@@ -61,30 +61,8 @@ def _assert_same_search(out_a, out_b, ga_a, ga_b):
 
 
 # ---------------------------------------------------------------------------
-# single-population engine: run_async == run
+# one engine's dispatch half
 # ---------------------------------------------------------------------------
-
-@pytest.mark.ci
-def test_run_async_bit_for_bit_matches_run():
-    cfg = nsga2.NSGA2Config(pop_size=10, n_generations=6, seed=4)
-    sync = nsga2.NSGA2(20, (2, 3), _bitcount_eval, cfg)
-    out_sync = sync.run()
-    asyn = nsga2.NSGA2(20, (2, 3), _bitcount_eval, cfg)
-    out_async = asyn.run_async(_deferred_dispatch())
-    _assert_same_search(out_sync, out_async, sync, asyn)
-
-
-@pytest.mark.ci
-def test_run_async_without_memo_matches_naive_engine():
-    cfg = nsga2.NSGA2Config(pop_size=8, n_generations=4, seed=1, memoize=False)
-    sync = nsga2.NSGA2(16, (), _bitcount_eval, cfg)
-    out_sync = sync.run()
-    asyn = nsga2.NSGA2(16, (), _bitcount_eval, cfg)
-    out_async = asyn.run_async(_deferred_dispatch())
-    np.testing.assert_array_equal(out_sync["objs"], out_async["objs"])
-    np.testing.assert_array_equal(out_sync["masks"], out_async["masks"])
-    assert sync.n_evaluations == asyn.n_evaluations
-
 
 @pytest.mark.ci
 def test_dispatch_pool_defers_commit_until_resolve():

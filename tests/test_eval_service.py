@@ -489,6 +489,32 @@ def test_shared_memo_persists_and_reloads(tmp_path):
 # ---------------------------------------------------------------------------
 
 
+def test_service_backend_answers_as_the_search_memo(tmp_path):
+    """``make_service_backend``'s objectives are the ones a search with the
+    same config stores in its memo, so the two memos are interchangeable."""
+    from repro.core import codesign
+
+    cfg = codesign.CodesignConfig(
+        dataset="seeds", pop_size=4, n_generations=2, step_scale=0.1,
+        max_steps=30, memo_path=str(tmp_path / "memo"),
+    )
+    codesign.run_codesign(cfg)
+    memo = memo_store.load_memo(cfg.memo_path, cfg.memo_fingerprint())
+    backend = codesign.make_service_backend(cfg, wave_slots=2)
+    n_mask = backend["n_mask_bits"]
+    keys = list(memo)
+    masks = np.stack([np.frombuffer(k[:n_mask], np.uint8) for k in keys]).astype(bool)
+    cats = np.stack([np.frombuffer(k[n_mask:], np.int64) for k in keys])
+    half = len(keys) // 2
+    got = backend["stacked_evaluate"](
+        [(masks[:half], cats[:half]), (masks[half:], cats[half:])]
+    )
+    assert len(keys) > 2
+    np.testing.assert_array_equal(
+        np.concatenate(got), np.stack([memo[k] for k in keys])
+    )
+
+
 def test_concurrent_qat_search_equals_solo_real_evaluator():
     """Tier-1 acceptance: concurrent == alone on the real QAT objective."""
     from repro.core import codesign

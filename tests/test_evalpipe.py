@@ -5,8 +5,8 @@ Two load-bearing properties.  First, the stage primitives
 only split the planned rows, never invent/drop/defer-a-must-train, and
 commit writes in plan order whatever order the screen chose.  Second,
 the regression the tentpole promised: with screening disabled — or with
-a screen that defers nothing — every driver (blocking, async
-single-engine, sequential/stacked/async islands, eval-service) is
+a screen that defers nothing — every driver (blocking engine,
+sequential/stacked/dispatched island schedules, eval-service) is
 bit-for-bit the PR-8 search: same fronts, same memo insertion order,
 same ``n_evaluations``/``n_memo_hits`` counters, and checkpoint
 round-trips that include the deferred side table.
@@ -169,15 +169,6 @@ def test_blocking_engine_passthrough_screen_is_bit_for_bit():
     ref = _summary(ref_eng, ref_eng.run())
     eng = nsga2.NSGA2(N_BITS, CATS, _objective, _ga(), screen=_passthrough_screen)
     got = _summary(eng, eng.run())
-    assert got == ref
-
-
-@pytest.mark.ci
-def test_async_engine_passthrough_screen_is_bit_for_bit():
-    ref_eng = nsga2.NSGA2(N_BITS, CATS, _objective, _ga())
-    ref = _summary(ref_eng, ref_eng.run())
-    eng = nsga2.NSGA2(N_BITS, CATS, _objective, _ga(), screen=_passthrough_screen)
-    got = _summary(eng, eng.run_async(_dispatch))
     assert got == ref
 
 

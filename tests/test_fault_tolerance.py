@@ -50,10 +50,26 @@ def _engine(evaluate=_bitcount_eval, **kw):
     return nsga2.NSGA2(24, (), evaluate, cfg)
 
 
-def _island_driver(evaluate=_bitcount_eval):
+def _stacked_bitcount(batches):
+    return [_bitcount_eval(m, c) if m.shape[0] else None for m, c in batches]
+
+
+def _deferred_bitcount(masks, cats):
+    m, c = masks.copy(), cats.copy()
+    return lambda: _bitcount_eval(m, c)
+
+
+def _island_driver(evaluate=_bitcount_eval, schedule="sequential"):
     cfg = nsga2.NSGA2Config(pop_size=5, n_generations=5, seed=1)
-    icfg = nsga2.IslandConfig(num_islands=3, migration_interval=2, migration_size=1)
-    return nsga2.IslandNSGA2(20, (), evaluate, cfg, icfg)
+    icfg = nsga2.IslandConfig(
+        num_islands=3, migration_interval=2, migration_size=1,
+        stacked=schedule == "stacked", async_pipeline=schedule == "dispatched",
+    )
+    return nsga2.IslandNSGA2(
+        20, (), evaluate, cfg, icfg,
+        stacked_evaluate=_stacked_bitcount if schedule == "stacked" else None,
+        dispatch_evaluate=_deferred_bitcount if schedule == "dispatched" else None,
+    )
 
 
 def _assert_same_front(out, ref):
@@ -181,13 +197,12 @@ def test_snapshot_rejects_wrong_search_config():
 # -- host-restart: durable checkpoint through the real CheckpointManager ----
 
 
-@pytest.mark.ci
-def test_island_checkpoint_restart_matches_uninterrupted(tmp_path):
-    ref_driver = _island_driver()
+def _check_island_restart(tmp_path, schedule):
+    ref_driver = _island_driver(schedule=schedule)
     ref = ref_driver.run()
 
     mgr = CheckpointManager(str(tmp_path / "ck"), keep_n=2)
-    interrupted = _island_driver()
+    interrupted = _island_driver(schedule=schedule)
 
     def hook(driver, gens_done):
         st = driver.state_dict()
@@ -200,7 +215,7 @@ def test_island_checkpoint_restart_matches_uninterrupted(tmp_path):
     mgr.wait()  # the boundary-2 write must be durable before the "restart"
 
     # fresh process: a brand-new driver restored from disk
-    resumed = _island_driver()
+    resumed = _island_driver(schedule=schedule)
     tree, manifest = mgr.restore()
     assert manifest["step"] == 2
     resumed.set_state({"arrays": tree, "meta": manifest["extra"]["meta"]})
@@ -215,6 +230,20 @@ def test_island_checkpoint_restart_matches_uninterrupted(tmp_path):
     resumed_rows = sum(r["n_evals"] for r in resumed.agg_history[2:])
     assert out["n_evaluations"] == ref["n_evaluations"]
     assert resumed_rows == sum(r["n_evals"] for r in ref_driver.agg_history[2:])
+    return out
+
+
+@pytest.mark.ci
+def test_island_checkpoint_restart_matches_uninterrupted(tmp_path):
+    _check_island_restart(tmp_path, "sequential")
+
+
+@pytest.mark.ci
+@pytest.mark.parametrize("schedule", ["stacked", "dispatched"])
+def test_island_checkpoint_restart_on_each_schedule(tmp_path, schedule):
+    out = _check_island_restart(tmp_path, schedule)
+    # and the resumed search is the sequential schedule's, bit for bit
+    _assert_same_result(out, _island_driver().run())
 
 
 # -- device loss: in-process rollback + memo-backed replay -------------------

@@ -241,28 +241,6 @@ def test_topology_none_runs_independent_islands():
 
 
 @pytest.mark.ci
-def test_stratified_init_bands_partition_density_range():
-    drv = nsga2.IslandNSGA2(
-        16, (), _bitcount_eval,
-        nsga2.NSGA2Config(pop_size=6, n_generations=1, seed=0),
-        nsga2.IslandConfig(num_islands=4, stratify_init=True),
-    )
-    bands = [isl.cfg.init_density for isl in drv.islands]
-    lo, hi = nsga2.NSGA2Config().init_density
-    assert bands[0][0] == pytest.approx(lo)
-    assert bands[-1][1] == pytest.approx(hi)
-    for (a, b), (c, d) in zip(bands, bands[1:]):
-        assert b == pytest.approx(c) and a < b
-    # default (stratify off): every island seeds from the full band
-    flat = nsga2.IslandNSGA2(
-        16, (), _bitcount_eval,
-        nsga2.NSGA2Config(pop_size=6, n_generations=1, seed=0),
-        nsga2.IslandConfig(num_islands=4),
-    )
-    assert all(isl.cfg.init_density == (lo, hi) for isl in flat.islands)
-
-
-@pytest.mark.ci
 def test_island_config_validation():
     with pytest.raises(ValueError):
         nsga2.IslandConfig(topology="torus")

@@ -110,20 +110,15 @@ def _analytic_ga(**kw):
         h = masks.shape[1] // 2
         return np.stack([masks[:, :h].mean(1), 1.0 - masks[:, h:].mean(1)], axis=1)
 
-    def dispatch(masks, cats):
-        objs = evaluate(masks, cats)
-        return lambda: objs
-
-    ga = nsga2.NSGA2(16, (3, 4), evaluate,
-                     nsga2.NSGA2Config(pop_size=12, n_generations=4, seed=5, **kw))
-    return ga, dispatch
+    return nsga2.NSGA2(16, (3, 4), evaluate,
+                       nsga2.NSGA2Config(pop_size=12, n_generations=4, seed=5, **kw))
 
 
-@pytest.mark.parametrize("driver", ["run", "run_async"])
+@pytest.mark.parametrize("driver", ["run"])
 def test_history_times_are_the_generation_and_evaluate_spans(driver):
-    ga, dispatch = _analytic_ga()
+    ga = _analytic_ga()
     with spans.recording() as log:
-        out = ga.run() if driver == "run" else ga.run_async(dispatch)
+        out = getattr(ga, driver)()
     recs = log.spans
     gens = [s for s in recs if s["name"] == "nsga2.generation"]
     evals = [s for s in recs if s["name"] == "nsga2.evaluate"]
@@ -135,6 +130,34 @@ def test_history_times_are_the_generation_and_evaluate_spans(driver):
             assert recs[g["parent"]]["name"] in ("nsga2.generation", "nsga2.setup")
     assert _names(log).count("nsga2.setup") == 1
     assert _names(log).count("nsga2.plan") == 1 + len(gens)
+
+
+@pytest.mark.parametrize("schedule", ["sequential", "stacked", "dispatched"])
+def test_island_history_is_one_generation_span_per_island_and_wave_shares(schedule):
+    def evaluate(masks, cats):
+        h = masks.shape[1] // 2
+        return np.stack([masks[:, :h].mean(1), 1.0 - masks[:, h:].mean(1)], axis=1)
+
+    K, G = 3, 4
+    drv = nsga2.IslandNSGA2(
+        16, (3, 4), evaluate, nsga2.NSGA2Config(pop_size=8, n_generations=G, seed=5),
+        nsga2.IslandConfig(num_islands=K, migration_interval=2,
+                           stacked=schedule == "stacked",
+                           async_pipeline=schedule == "dispatched"),
+    )
+    with spans.recording() as log:
+        out = drv.run()
+    gens = [s for s in log.spans if s["name"] == "nsga2.generation"]
+    # every island's begin opens its span before the wave is evaluated
+    assert [s["attrs"]["gen"] for s in gens] == [g for g in range(G) for _ in range(K)]
+    assert _names(log).count("nsga2.setup") == 1
+    for g, agg in enumerate(out["history"]):
+        shares = [h[g]["gen_s"] for h in out["island_history"]]
+        assert [h[g]["gen"] for h in out["island_history"]] == [g] * K
+        assert len(set(shares)) == 1, shares
+        assert agg["gen"] == g and agg["gen_s"] == round(sum(shares), 4)
+        # each island's span lies inside the wave the shares add up to
+        assert max(s["dur"] for s in gens[g * K:(g + 1) * K]) <= agg["gen_s"] + 1e-3
 
 
 AXES = ("adc", "act", "wprec")
